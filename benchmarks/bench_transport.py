@@ -301,7 +301,7 @@ def bench_shardmap_traces() -> dict:
     x = np.ones((n * sched.num_slots, FEAT), np.float32)
     calls = 6
     t0 = time.perf_counter()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for _ in range(calls):
             jax.block_until_ready(f(x))
     elapsed = time.perf_counter() - t0

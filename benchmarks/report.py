@@ -11,7 +11,23 @@ import json
 from repro import configs
 from repro.configs.shapes import SHAPES
 
-PEAK, HBM, ICI = 197e12, 819e9, 50e9
+# Per-chip peaks keyed by ``jax.devices()[0].device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect over four links
+# (50 GB/s per link, the per-link figure the collective term uses).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm": 819e9, "ici": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The peaks of ``device_kind``; a device not in the table is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peaks recorded for device_kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 def model_flops(arch: str, shape: str) -> float:
@@ -26,10 +42,10 @@ def model_flops(arch: str, shape: str) -> float:
     return 2.0 * n * sp.global_batch          # decode: 1 new token
 
 
-def terms(r):
-    tc = r["flops_per_device"] / PEAK
-    tm = r["hbm_bytes_per_device"] / HBM
-    tl = r["collectives"]["total"] / ICI
+def terms(r, pk):
+    tc = r["flops_per_device"] / pk["flops"]
+    tm = r["hbm_bytes_per_device"] / pk["hbm"]
+    tl = r["collectives"]["total"] / pk["ici"]
     dom = max((tc, "compute"), (tm, "memory"), (tl, "collective"))[1]
     return tc, tm, tl, dom
 
@@ -46,7 +62,7 @@ HINTS = {
 }
 
 
-def table(results, mesh="16x16", compare=None):
+def table(results, pk, mesh="16x16", compare=None):
     rows = []
     comp_map = {}
     if compare:
@@ -62,14 +78,14 @@ def table(results, mesh="16x16", compare=None):
             continue
         if r.get("mesh") != mesh:
             continue
-        tc, tm, tl, dom = terms(r)
+        tc, tm, tl, dom = terms(r, pk)
         mf = model_flops(r["arch"], r["shape"])
         ratio = mf / (r["flops_per_device"] * r["n_devices"])
         note = HINTS[dom]
         if compare:
             b = comp_map.get((r["arch"], r["shape"]))
             if b:
-                btc, btm, btl, _ = terms(b)
+                btc, btm, btl, _ = terms(b, pk)
                 x = max(btc, btm, btl) / max(tc, tm, tl)
                 note = f"{x:,.0f}x vs baseline bound"
         print(f"| {r['arch']} | {r['shape']} |{fmt(tc)} |{fmt(tm)} "
@@ -80,18 +96,22 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--baseline", required=True)
     ap.add_argument("--optimized", default=None)
+    ap.add_argument("--device-kind", default="TPU v5 lite",
+                    help="device_kind whose peaks price the dry-run "
+                         "(one of PEAKS)")
     args = ap.parse_args()
+    pk = peaks(args.device_kind)
     base = json.load(open(args.baseline))["results"]
     print("### Baseline (paper-faithful defaults), single-pod 16x16, "
           "per-device terms\n")
-    table(base)
+    table(base, pk)
     if args.optimized:
         opt = json.load(open(args.optimized))["results"]
         print("\n### Optimized (hint-level 2 SP + kernel path), "
               "single-pod 16x16\n")
-        table(opt, compare=base)
+        table(opt, pk, compare=base)
         print("\n### Multi-pod 2x16x16 optimized (DCN axis active)\n")
-        table(opt, mesh="2x16x16", compare=base)
+        table(opt, pk, mesh="2x16x16", compare=base)
 
 
 if __name__ == "__main__":

@@ -29,7 +29,7 @@ for algo in ("xla", "ring_rs_ag", "hierarchical", "auto"):
         lambda v: mpix.mpix_allreduce(v, ("pod", "data"), algorithm=algo),
         mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(None),
         check_vma=False))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = np.asarray(f(x))
     assert np.allclose(out, x.reshape(8, 1, 4).sum(0))
     print(f"mpix_allreduce[{algo:>13s}] ok -> {out[0][:4]}")
@@ -51,7 +51,7 @@ g = jax.jit(compat.shard_map(                          # ... execute often
     lambda v: run_shardmap(plan, v, ("pod", "data")),
     mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(("pod", "data")),
     check_vma=False))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     recv = np.asarray(g(values.reshape(8 * 4, 2)))
 print("neighbor exchange ok, recv shape", recv.shape)
 print("quickstart OK")

@@ -27,7 +27,7 @@ def main():
     cfg = configs.get_smoke(ARCH)
     n = jax.device_count()
     mesh = compat.make_mesh((n, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = M.init_params(jax.random.key(0), cfg)
         reqs = jax.random.randint(jax.random.key(1), (BATCH, PROMPT), 2,
                                   cfg.vocab_size)

@@ -1,70 +1,45 @@
-"""Version-robust accessors for JAX APIs that moved across releases.
+"""JAX calls with the repo's shared defaults, and its compile cache.
 
-The repo targets the current JAX surface (``jax.shard_map``,
-``jax.set_mesh``, ``jax.lax.axis_size``, ``Mesh(..., axis_types=...)``)
-but must also run on older installs (0.4.x) where those live elsewhere
-or do not exist.  Every call site goes through this module instead of
-feature-testing jax inline.
+The repo is written against the installed JAX (``pyproject.toml`` pins
+it) and calls it directly; the two wrappers here only fix arguments
+every call site shares: ``shard_map`` with ``check_vma`` off by default,
+``make_mesh`` with Auto axis types.
 
-  * ``axis_size(name)``   — ``jax.lax.axis_size`` or the ``psum(1, name)``
-                            trick (special-cased by jax to a static int).
-  * ``shard_map(...)``    — ``jax.shard_map`` or the ``jax.experimental``
-                            version; the ``check_vma`` kwarg maps onto the
-                            old ``check_rep``.
-  * ``make_mesh(...)``    — drops ``axis_types`` when unsupported.
-  * ``set_mesh(mesh)``    — context manager; a no-op on versions without
-                            an ambient-mesh concept (every shard_map here
-                            carries its mesh explicitly, so nothing is
-                            lost).
-  * ``pallas_interpret()`` — re-export of the kernels-layer shim: should
-                            Pallas kernels (including the device-side
-                            ``PallasTransport``) run under the Pallas
-                            interpreter?  ``REPRO_PALLAS_INTERPRET=1``
-                            forces on, ``0`` forces off, unset auto-ons
-                            when no TPU backs the default backend.
+``enable_compile_cache()`` puts JAX's persistent compilation cache at a
+fixed path; the launchers and ``chip_smoke.py`` call it from ``main()``,
+never at import.
 """
 from __future__ import annotations
 
-import contextlib
+import os
+from pathlib import Path
 
 import jax
 
-from repro.kernels.compat import pallas_interpret  # noqa: F401 (re-export)
-
-
-def axis_size(name) -> int:
-    """Static size of a manual mesh axis (callable inside shard_map)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    # psum of the literal 1 is special-cased at trace time to the static
-    # axis size (a Python int), on every jax version.
-    return jax.lax.psum(1, name)
+# <checkout>/.jax_cache — fixed, so a later process finds what an
+# earlier one compiled (the path is part of the cache key)
+_REPO_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis_types where supported."""
+    """``jax.make_mesh`` with Auto axis_types."""
     axis_names = tuple(axis_names)
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = ((jax.sharding.AxisType.Auto,)
-                                * len(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), axis_names, devices=devices,
-                         **kwargs)
+    return jax.make_mesh(
+        tuple(axis_shapes), axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
-def set_mesh(mesh):
-    """Ambient-mesh context manager (no-op where jax has none)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return contextlib.nullcontext(mesh)
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is (JAX reads it
+    itself) and no other directory is set.  Otherwise the cache lives at
+    the fixed ``<checkout>/.jax_cache``."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(_REPO_CACHE)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -44,8 +44,6 @@ from repro.core.schedule import CommSchedule, make_round
 from repro.core.topology import Topology
 from repro.core.transport import _flat_rank
 
-from repro import compat
-
 
 def _axes_tuple(axis_names):
     return (axis_names,) if isinstance(axis_names, str) else tuple(axis_names)
@@ -141,7 +139,7 @@ def partitioned_ppermute(x: jax.Array, axis_name, perm,
             names = _axes_tuple(axis_name)
             n = 1
             for a in names:
-                n *= compat.axis_size(a)
+                n *= jax.lax.axis_size(a)
             sched = partitioned_schedule(n, perm, partitions)
             buf = jnp.concatenate([chunks, jnp.zeros_like(chunks)], axis=0)
             out = ShardMapTransport(n, names).run(sched, buf)
@@ -177,7 +175,7 @@ def allgather_matmul(x: jax.Array, w: jax.Array, axis_name, *,
     names = _axes_tuple(axis_name)
     n_ranks = 1
     for a in names:
-        n_ranks *= compat.axis_size(a)
+        n_ranks *= jax.lax.axis_size(a)
     axis_arg = names if len(names) > 1 else names[0]
     rank = _flat_rank(names)
     m_local = x.shape[0]
@@ -224,7 +222,7 @@ def matmul_reduce_scatter(x: jax.Array, w: jax.Array, axis_name, *,
     names = _axes_tuple(axis_name)
     n_ranks = 1
     for a in names:
-        n_ranks *= compat.axis_size(a)
+        n_ranks *= jax.lax.axis_size(a)
     axis_arg = names if len(names) > 1 else names[0]
     rank = _flat_rank(names)
     m = x.shape[0]

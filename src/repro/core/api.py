@@ -31,12 +31,11 @@ from repro.core.transport import (PallasTransport, ShardMapTransport,
                                   TransportError, _flat_rank)
 from repro.core.schedule import NotApplicable
 from repro.core.resilient import (Attempt, DegradationReport,
-                                  UnrecoverableError, resolve_resilience)
+                                  UnrecoverableError, announce,
+                                  resolve_resilience)
 from repro.core import chaos as _chaos
 from repro.core import selector
 from repro.core.algorithms import REGISTRY
-
-from repro import compat
 
 
 def _axes_tuple(axis_names) -> tuple[str, ...]:
@@ -55,7 +54,7 @@ def topology_from_axes(axis_names: Sequence[str]) -> Topology:
     Must be called inside shard_map (uses static axis sizes).
     """
     names = _axes_tuple(axis_names)
-    sizes = [compat.axis_size(n) for n in names]
+    sizes = [jax.lax.axis_size(n) for n in names]
     nranks = 1
     for s in sizes:
         nranks *= s
@@ -188,13 +187,15 @@ def _check_transport(transport: str) -> None:
 
 
 def _resolve_transport(transport: str, topo: Topology, nbytes: int,
-                       policy: str | None = None) -> str:
-    """Validate + resolve a transport name to a concrete substrate."""
+                       policy: str | None = None, schedule=None) -> str:
+    """Validate + resolve a transport name to a concrete substrate for
+    ``schedule`` (None: no schedule runs on it)."""
     _check_transport(transport)
     if transport == "auto":
         from repro.core import tuner  # local: avoid import cycle
         transport = tuner.select_transport(
-            topo, nbytes, policy=policy or _DEFAULT_POLICY)
+            topo, nbytes, policy=policy or _DEFAULT_POLICY,
+            schedule=schedule)
     return transport
 
 
@@ -220,12 +221,6 @@ def _transport_instance(kind: str, topo: Topology, names):
     return _chaos.wrap(cls(topo.nranks, names, topo=topo), _CHAOS_PLAN)
 
 
-def _make_transport(transport: str, topo: Topology, names, nbytes: int,
-                    policy: str | None = None):
-    kind = _resolve_transport(transport, topo, nbytes, policy)
-    return _transport_instance(kind, topo, names)
-
-
 # Degradation telemetry: every mpix_* call that needed the recovery
 # ladder appends its DegradationReport here; ``FaultTolerantLoop``
 # drains the list each step so a degraded mesh is *visible*, not silent.
@@ -246,7 +241,7 @@ def take_degradations() -> list:
 
 def _execute(collective: str, run, *, algorithm: str, policy,
              topo: Topology, nbytes: int, transport: str, resilience,
-             xla_ok: bool = True):
+             xla_ok: bool = True, schedule=None):
     """Shared execution path of every mpix_* collective.
 
     ``run(kind, algo)`` closes over the collective's buffers and does
@@ -261,7 +256,8 @@ def _execute(collective: str, run, *, algorithm: str, policy,
     substrate, refitted down the selector's algorithm ladder, and
     finally routed to the substrate's native lowering
     (``algorithm="xla"``, the system-MPI analogue) before a typed
-    ``UnrecoverableError`` is raised.
+    ``UnrecoverableError`` is raised.  ``schedule`` is what ``run``
+    executes, for collectives outside ``REGISTRY``.
 
     Honest taxonomy: values here are *traced*, so data-dependent
     verification (canary/checksum) is impossible at this layer —
@@ -276,7 +272,9 @@ def _execute(collective: str, run, *, algorithm: str, policy,
     opts = resolve_resilience(resilience)
     if algorithm == "xla":
         return run("xla", "xla")
-    kind = _resolve_transport(transport, topo, nbytes, policy)
+    if transport == "auto" and schedule is None:
+        schedule = _schedule(collective, algorithm, topo)
+    kind = _resolve_transport(transport, topo, nbytes, policy, schedule)
     if opts is None:
         return run(kind, algorithm)
 
@@ -286,6 +284,7 @@ def _execute(collective: str, run, *, algorithm: str, policy,
     def finish(out, rung):
         report.recovered_with = rung
         if report.degraded:
+            announce(report)
             _DEGRADATIONS.append(report)
         return out
 
@@ -352,12 +351,13 @@ def _execute(collective: str, run, *, algorithm: str, policy,
         f"algorithm", report)
 
 
-def _pad_to(x: jax.Array, mult: int):
-    flat = x.reshape(-1)
-    rem = (-flat.size) % mult
+def _pad_lead(x: jax.Array, mult: int):
+    """``x`` with zero rows appended to its leading dim up to a multiple
+    of ``mult``."""
+    rem = (-x.shape[0]) % mult
     if rem:
-        flat = jnp.concatenate([flat, jnp.zeros((rem,), x.dtype)])
-    return flat
+        x = jnp.pad(x, ((0, rem),) + ((0, 0),) * (x.ndim - 1))
+    return x
 
 
 # ---------------------------------------------------------------------------
@@ -406,9 +406,13 @@ def mpix_allreduce(x: jax.Array, axis_names, *, algorithm: str = "auto",
             return jax.lax.psum(x, names)
         sched = _schedule("allreduce", algo, topo)
         tr = _transport_instance(kind, topo, names)
-        flat = _pad_to(x, n)
-        out = tr.run(sched, flat.reshape(n, -1))
-        return out.reshape(-1)[: x.size].reshape(x.shape)
+        # n chunks cut along the leading dim, zero padded up to a
+        # multiple of n: no relayout of the minor dims
+        xs = x.reshape(1) if x.ndim == 0 else x
+        lead = xs.shape[0]
+        xs = _pad_lead(xs, n)
+        out = tr.run(sched, xs.reshape((n, -1) + xs.shape[1:]))
+        return out.reshape(xs.shape)[:lead].reshape(x.shape)
 
     return _execute("allreduce", run, algorithm=algorithm, policy=policy,
                     topo=topo, nbytes=nbytes, transport=transport,
@@ -617,7 +621,7 @@ def mpix_neighbor_alltoallv(x: jax.Array, axis_names, plan, *,
     return _execute("neighbor_alltoallv", run, algorithm=plan.name,
                     policy=None, topo=plan.topo, nbytes=nbytes,
                     transport=transport, resilience=resilience,
-                    xla_ok=False)
+                    xla_ok=False, schedule=plan.schedule)
 
 
 # ---------------------------------------------------------------------------
@@ -669,6 +673,7 @@ def mpix_allreduce_rmsnorm(x: jax.Array, axis_names, scale: jax.Array, *,
                 rung="pallas", algorithm="fused", attempt=0,
                 outcome="fault", detail=str(e)))
             report.recovered_with = "shardmap"
+            announce(report)
             _DEGRADATIONS.append(report)
     y = mpix_allreduce(x, names, algorithm=algorithm, policy=policy,
                        topo=topo, resilience=resilience)

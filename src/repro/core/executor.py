@@ -706,33 +706,30 @@ class _ExecRound:
         self.dup_targets = rnd.reduce and any(
             len(np.unique(row[m])) != int(m.sum())
             for row, m in zip(t, self.t_mask))
-        self._jnp = None
+        self._tables = None
 
-    def jnp_tables(self):
-        """Device-resident gather/scatter tables AND their ``jnp.where``
-        masks, materialized once and reused by every subsequent trace
-        (persistent-collective style).  The scratch-safe indices
-        (``-1 -> num_slots``) and the validity masks are precomputed
-        here as device constants instead of being rebuilt from
-        ``table >= 0`` comparisons inside every lowering.
-        ``ensure_compile_time_eval`` makes them concrete arrays even
-        when first touched from inside a jit/shard_map trace — caching
-        a tracer would leak it into later traces.
+    def tables(self):
+        """The shard_map lowering's gather/scatter tables AND their
+        ``jnp.where`` masks, built once as numpy and embedded as
+        constants by every trace that reads them.  The scratch-safe
+        indices (``-1 -> num_slots``) and the validity masks are
+        precomputed here instead of being rebuilt from ``table >= 0``
+        comparisons inside every lowering.  No jax array is cached: an
+        array made inside one trace carries that trace's mesh and would
+        break a later trace on another mesh.
         Returns (gather_safe, gather_mask, scatter_safe, scatter_mask).
         """
-        if self._jnp is None:
-            import jax
+        if self._tables is None:
             nb = self.num_slots
-            with jax.ensure_compile_time_eval():
-                self._jnp = (
-                    jnp.asarray(np.where(self.gather_idx >= 0,
-                                         self.gather_idx, nb), np.int32),
-                    jnp.asarray(self.gather_idx >= 0),
-                    jnp.asarray(np.where(self.scatter_idx >= 0,
-                                         self.scatter_idx, nb), np.int32),
-                    jnp.asarray(self.scatter_idx >= 0),
-                )
-        return self._jnp
+            self._tables = (
+                np.where(self.gather_idx >= 0, self.gather_idx,
+                         nb).astype(np.int32),
+                self.gather_idx >= 0,
+                np.where(self.scatter_idx >= 0, self.scatter_idx,
+                         nb).astype(np.int32),
+                self.scatter_idx >= 0,
+            )
+        return self._tables
 
 
 class CompiledExec:
@@ -825,8 +822,6 @@ class CompiledExec:
                      else np.asarray(self.local_pre, np.int64))
         self._post = (None if self.local_post is None
                       else np.asarray(self.local_post, np.int64))
-        self._jnp_pre = None
-        self._jnp_post = None
 
     # -- pass 3: makespan planning + tail-chunk pipelining ----------------
     def _event_deps(self, nrounds: int) -> list[int]:
@@ -1001,34 +996,27 @@ class CompiledExec:
 
     # -- shard_map backend (called inside an ambient shard_map trace) -----
     def run_shardmap(self, buf, rank, axis_arg):
-        import jax
-
         self.trace_count += 1
         nb = self.num_slots
         if self._pre is not None:
-            if self._jnp_pre is None:
-                with jax.ensure_compile_time_eval():
-                    self._jnp_pre = jnp.asarray(self._pre, jnp.int32)
-            buf = buf[self._jnp_pre[rank]]
+            buf = buf[jnp.asarray(self._pre, jnp.int32)[rank]]
         scratch = jnp.zeros((1,) + buf.shape[1:], buf.dtype)
         x = jnp.concatenate([buf, scratch], axis=0)
         for rnd in self._rounds:
             x = self._shardmap_round(rnd, x, rank, axis_arg, nb)
         out = x[:nb]
         if self._post is not None:
-            if self._jnp_post is None:
-                with jax.ensure_compile_time_eval():
-                    self._jnp_post = jnp.asarray(self._post, jnp.int32)
-            out = out[self._jnp_post[rank]]
+            out = out[jnp.asarray(self._post, jnp.int32)[rank]]
         return out
 
     def _shardmap_round(self, rnd: _ExecRound, x, rank, axis_arg, nb):
         import jax
 
         kdims = (rnd.k,) + (1,) * (x.ndim - 1)
-        # safe indices and where-masks are baked device constants
-        # (jnp_tables): no per-trace `>= 0` comparisons or -1 clamping
-        g_safe, g_mask, t_safe, t_mask = rnd.jnp_tables()
+        # safe indices and where-masks are baked numpy tables
+        # (``tables``), embedded here as constants of this trace
+        g_safe, g_mask, t_safe, t_mask = (
+            jnp.asarray(t) for t in rnd.tables())
         # Gather payload; -1 slots read the scratch row and are zeroed.
         payload = x[g_safe[rank]]
         payload = jnp.where(g_mask[rank].reshape(kdims), payload, 0)

@@ -1,42 +1,37 @@
 """Device-side Pallas lowering of a ``CompiledExec`` (the paper's
 GPU-aware pillar): the WHOLE compiled round sequence as ONE kernel.
 
-Both existing transports lower every compiled ``CommRound`` to a
+Both other transports lower every compiled ``CommRound`` to a
 gather-permute-scatter around ``shard_map``/``ppermute``, so an R-round
 schedule pays R XLA collective launches.  This module takes the baked
 numpy index tables of a ``CompiledExec`` (``_ExecRound.src/dst/g_safe/
 g_mask/t_safe/t_mask`` plus the folded local pre/post permutations) and
-embeds them as kernel-resident constants in a single ``pl.pallas_call``
-over the *global* slot buffer ``[nranks, num_slots, *slot]``:
+emits them as static slot copies in a single ``pl.pallas_call`` over the
+*global* slot buffer ``[nranks, num_slots, *slot]``:
 
-  * the buffer is staged once into a VMEM scratch work buffer; every
-    slot route is emitted with *static* indices (Pallas kernels cannot
-    capture array constants, and static indices are what lets Mosaic
-    lower each move as a plain VMEM copy), so ``-1`` routes simply emit
-    nothing — no scratch row, unlike the fancy-indexed backends;
-  * each round runs in two phases that preserve ppermute semantics
-    exactly: phase 1 gathers every edge's payload from the pre-round
-    state (reads only — intra-round hazards and (r, r) self-copies are
-    safe by construction), phase 2 lands every write
-    (``.at[t].set``, or ``.at[t].add`` for reduce rounds, which
-    accumulate in scratch instead of materializing an inbox);
-  * ``chunks > 1`` tiles the slot row axis onto the Pallas grid — the
-    same always-legal row decomposition as ``Transport.run_chunked``
-    (rows never mix; the slot-granularity sibling is ``split_round``) —
-    and Pallas's grid pipelining double-buffers the block transfers:
-    chunk ``i+1``'s HBM->VMEM copy is issued while chunk ``i`` drains
-    through the permutation network.  Still one kernel launch.
+  * each slot is laid out as rows of 128 lanes (``[rows, 128]``, zero
+    padded), the TPU's native tile, so every slot move is a whole-tile
+    VMEM copy; indices are Python ints (Pallas kernels cannot capture
+    array constants), so ``-1`` routes simply emit nothing;
+  * each round runs in two phases that keep ppermute semantics exactly:
+    phase 1 stages into an inbox scratch every payload whose source slot
+    the round overwrites (so it is read from the pre-round state), phase
+    2 lands every write through the work ref — an overwrite, or for
+    reduce rounds ``work + payload`` in the simulator's add order, which
+    keeps the result bitwise equal to ``SimTransport.run_reference``;
+  * the row axis is tiled onto the Pallas grid: ``chunks`` sets the
+    least number of grid steps, and a buffer whose blocks would not fit
+    ``VMEM_BUDGET`` is cut into more steps.  Rows never mix, so every
+    tiling is bit-identical; grid pipelining double-buffers the block
+    transfers.  Still one kernel launch.  A schedule with so many slots
+    that one (8, 128) tile per slot exceeds the budget raises a typed
+    ``TransportError`` that names the bound.
 
 R rounds -> 1 launch is the whole point: ``PallasExec.launches`` counts
 launches so the benchmark can assert the amortization (R -> 1 over the
-corpus).  On a CPU/GPU host the kernel runs under the Pallas interpreter
-(``kernels.compat.pallas_interpret``), bit-exact vs
-``SimTransport.run_reference`` — that is what makes the transport
-testable in tier-1 CI.  On real multi-chip TPU topologies the same
-structure extends to ``pltpu.make_async_remote_copy`` RDMA rounds
-(per-chip local buffers, no global gather); that variant needs device
-semaphores the interpreter cannot model and is gated behind actual TPU
-presence — see the README "Device-side transport" subsection.
+corpus).  On a TPU the kernel is compiled by Mosaic; where no TPU backs
+the process (the CPU test suite) it runs under the Pallas interpreter
+(``kernels.compat.pallas_interpret``), with the same bitwise contract.
 """
 from __future__ import annotations
 
@@ -45,16 +40,44 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.executor import CompiledExec, get_executor
 from repro.core.schedule import CommSchedule, validate_schedules_enabled
 from repro.core.topology import Topology
-from repro.kernels.compat import pallas_interpret, tpu_compiler_params
+from repro.core.transport import TransportError
+from repro.kernels.compat import pallas_interpret
 
 
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
+# Scoped VMEM the kernel asks Mosaic for (a v5e core has 128 MiB), and
+# the part of it the block plan may fill: two buffers each of the in and
+# out blocks, the work scratch and the inbox.  The rest is headroom for
+# Mosaic's own scratch.
+VMEM_LIMIT = 64 << 20
+VMEM_BUDGET = 48 << 20
+_LANES = 128
+_MAX_BLOCK_ROWS = 512        # rows of 128 lanes per slot per grid step
+
+
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
+
+
+def _sublanes(itemsize: int) -> int:
+    """Rows of one native tile: (8, 128) at 32 bits, (16, 128) at 16."""
+    return 8 * max(1, 4 // itemsize)
+
+
+def _round_plan(rnd):
+    """(live, staged) of one compiled round: the live (edge, slot)
+    writes in order, and the subset whose source slot the round itself
+    overwrites — those payloads are staged in the inbox first."""
+    live = [(e, j) for e in range(len(rnd.src)) for j in range(rnd.k)
+            if rnd.t_mask[e, j]]
+    written = {(int(rnd.dst[e]), int(rnd.t_safe[e, j])) for e, j in live}
+    staged = [(e, j) for e, j in live if rnd.g_mask[e, j]
+              and (int(rnd.src[e]), int(rnd.g_safe[e, j])) in written]
+    return live, staged
 
 
 class PallasExec:
@@ -67,7 +90,7 @@ class PallasExec:
     ``run_reference`` oracle check it bit-for-bit.  ``launches`` counts
     ``pallas_call`` invocations (one per ``run``, regardless of round
     count R); ``jit_traces`` counts actual lowerings (one per (shape,
-    dtype, chunks) thanks to the jit cache — the persistent-collective
+    dtype, grid) thanks to the jit cache — the persistent-collective
     property, same contract as ``CompiledExec.trace_count``).
     """
 
@@ -78,124 +101,171 @@ class PallasExec:
         self.rounds = ex.rounds_after
         self.interpret = (pallas_interpret() if interpret is None
                           else bool(interpret))
+        self._plans = [_round_plan(r) for r in ex._rounds]
+        self.inbox_slots = max([1] + [len(st) for _, st in self._plans])
+        # slot-sized VMEM blocks: 2 in + 2 out buffers, work (only when a
+        # local_post permutation needs a copy apart from the output) and
+        # the inbox
+        n_s = self.nranks * self.num_slots
+        self.vmem_slot_blocks = ((4 + (ex._post is not None)) * n_s
+                                 + self.inbox_slots)
         self.launches = 0
         self.jit_traces = 0
         self._jitted: dict = {}
 
+    @property
+    def min_vmem_bytes(self) -> int:
+        """VMEM of the smallest legal block: one native tile (4 KiB at
+        any dtype) per slot block."""
+        return self.vmem_slot_blocks * 8 * _LANES * 4
+
+    @property
+    def fits(self) -> bool:
+        return self.min_vmem_bytes <= VMEM_BUDGET
+
     # -- kernel body ------------------------------------------------------
-    def _kernel(self, in_ref, out_ref, work):
-        """Executes on refs shaped [n, s, C, F].
+    def _kernel(self, in_ref, out_ref, *scratch):
+        """Executes on refs shaped [n, s, rows, 128].
 
         Every index comes from the baked numpy tables as a Python int,
-        so the whole routing program is kernel-resident: Pallas kernels
-        cannot capture array constants, and static indices are exactly
-        what lets Mosaic turn each slot move into a plain VMEM copy
-        (no dynamic-gather lowering).  ``-1`` routes (masked slots) are
-        simply not emitted — no scratch row is needed here, unlike the
-        fancy-indexed numpy/shard_map backends."""
+        so the whole routing program is kernel-resident and each slot
+        move is a whole-tile VMEM copy (no dynamic gather, no scatter).
+        ``-1`` routes (masked slots) emit nothing."""
         self.jit_traces += 1
         ex = self.ex
-        n = self.nranks
+        n, s = self.nranks, self.num_slots
+        *work, inbox = scratch
+        # without local_post the output block is the work buffer
+        buf = work[0] if work else out_ref
         # stage in + local_pre fold (non-bijective pre survives folding)
-        for r in range(n):
-            row = in_ref[r]                              # [s, C, F]
-            if ex._pre is not None:
-                row = jnp.stack([row[int(i)] for i in ex._pre[r]])
-            work[r] = row
-        zero = jnp.zeros(work.shape[2:], work.dtype)     # one slot block
-        for rnd in ex._rounds:
-            m = len(rnd.src)
-            # phase 1 — gather every edge's payload from the PRE-round
-            # state (ppermute semantics: no write is visible to any read
-            # of the same round; (r, r) self-pairs and intra-round
-            # hazards are correct by construction); masked gathers are
-            # send-zeros
-            vals = []
-            for e in range(m):
-                row = work[int(rnd.src[e])]              # [s, C, F]
-                vals.append([
-                    row[int(rnd.g_safe[e, j])]
-                    if rnd.g_mask[e, j] else zero
-                    for j in range(rnd.k)])
-            # phase 2 — land every write on its destination row; reduce
-            # rounds accumulate in the work scratch.  dst values are
-            # distinct within a round (perm is a matching), so reading
-            # ``work[dst]`` here still sees the pre-round row.  The
-            # masked-gather zero adds are kept: bit-parity with run_sim
-            # (x + 0.0 normalizes -0.0; chained adds in j order match
-            # np.add.at element order even for duplicate targets).
-            for e in range(m):
-                dst = int(rnd.dst[e])
-                cur = work[dst]
-                for j in range(rnd.k):
-                    if not rnd.t_mask[e, j]:
-                        continue                         # dropped slot
-                    t = int(rnd.t_safe[e, j])
-                    if rnd.reduce:
-                        cur = cur.at[t].add(vals[e][j])
-                    else:
-                        cur = cur.at[t].set(vals[e][j])
-                work[dst] = cur
+        if ex._pre is None:
+            buf[...] = in_ref[...]
+        else:
+            for r in range(n):
+                for i in range(s):
+                    buf[r, i] = in_ref[r, int(ex._pre[r, i])]
+        zero = jnp.zeros(buf.shape[2:], buf.dtype)       # one slot block
+        for rnd, (live, staged) in zip(ex._rounds, self._plans):
+            def payload(e, j, rnd=rnd):
+                if not rnd.g_mask[e, j]:
+                    return zero                  # masked gathers send 0
+                return buf[int(rnd.src[e]), int(rnd.g_safe[e, j])]
+
+            # phase 1 — stage the payloads whose source slot this round
+            # overwrites, so they are read from the PRE-round state
+            # (ppermute semantics: no write of a round is visible to
+            # any of its reads); every other payload is read in phase 2
+            box = {}
+            for e, j in staged:
+                box[e, j] = len(box)
+                inbox[box[e, j]] = payload(e, j)
+            # phase 2 — land every write.  The masked-gather zero adds
+            # are kept: bit-parity with run_sim (x + 0.0 normalizes
+            # -0.0; chained adds in j order match np.add.at element
+            # order even for duplicate targets).
+            for e, j in live:
+                dst, t = int(rnd.dst[e]), int(rnd.t_safe[e, j])
+                val = inbox[box[e, j]] if (e, j) in box else payload(e, j)
+                buf[dst, t] = buf[dst, t] + val if rnd.reduce else val
         # local_post + drain
-        for r in range(n):
-            row = work[r]
-            if ex._post is not None:
-                row = jnp.stack([row[int(i)] for i in ex._post[r]])
-            out_ref[r] = row
+        if ex._post is not None:
+            for r in range(n):
+                for i in range(s):
+                    out_ref[r, i] = buf[r, int(ex._post[r, i])]
 
     # -- launch -----------------------------------------------------------
-    def _build(self, c: int, cb: int, f: int, dtype) -> callable:
+    def _plan(self, elems: int, itemsize: int, chunks: int):
+        """(rows, block_rows): the slot's 128-lane row count after
+        padding, and the rows of one grid step."""
+        sub = _sublanes(itemsize)
+        rows = _cdiv(elems, _LANES)
+        per_row = self.vmem_slot_blocks * _LANES * itemsize
+        max_rows = min(_MAX_BLOCK_ROWS, VMEM_BUDGET // per_row // sub * sub)
+        if max_rows < sub:
+            raise TransportError(
+                f"pallas transport: {self.ex.schedule.name} needs "
+                f"{self.min_vmem_bytes} B of VMEM for one ({sub}, "
+                f"{_LANES}) tile in each of its {self.vmem_slot_blocks} "
+                f"slot blocks, over the {VMEM_BUDGET} B bound "
+                f"(VMEM_BUDGET); run it on the shardmap transport",
+                transport="pallas")
+        if chunks == 1 and _cdiv(rows, sub) * sub <= max_rows:
+            return rows, rows                  # one block, the whole slot
+        steps = max(chunks, _cdiv(rows, max_rows))
+        block = _cdiv(_cdiv(rows, steps), sub) * sub
+        return steps * block, block
+
+    def _build(self, rows: int, block: int, dtype) -> callable:
         n, s = self.nranks, self.num_slots
-        grid = (c // cb,)
-        spec = pl.BlockSpec((n, s, cb, f), lambda i: (0, 0, i, 0))
+        spec = pl.BlockSpec((n, s, block, _LANES), lambda i: (0, 0, i, 0))
+        scratch = [pltpu.VMEM((self.inbox_slots, block, _LANES), dtype)]
+        if self.ex._post is not None:
+            scratch.insert(0, pltpu.VMEM((n, s, block, _LANES), dtype))
         return pl.pallas_call(
             self._kernel,
-            grid=grid,
+            grid=(rows // block,),
             in_specs=[spec],
             out_specs=spec,
-            out_shape=jax.ShapeDtypeStruct((n, s, c, f), dtype),
-            scratch_shapes=[_vmem((n, s, cb, f), dtype)],
-            compiler_params=tpu_compiler_params(
-                dimension_semantics=("arbitrary",)),
+            out_shape=jax.ShapeDtypeStruct((n, s, rows, _LANES), dtype),
+            scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=VMEM_LIMIT),
             interpret=self.interpret,
         )
+
+    def _prepare(self, shape, dtype, chunks: int):
+        """Validate a global buffer shape; returns (elems, rows, block,
+        jitted kernel)."""
+        n, s = self.nranks, self.num_slots
+        if tuple(shape[:2]) != (n, s):
+            raise ValueError(
+                f"PallasExec.run: buffer [{tuple(shape)}] does not match "
+                f"[nranks={n}, num_slots={s}, *slot]")
+        slot = tuple(shape[2:])
+        if chunks < 1:
+            raise ValueError(f"PallasExec.run: chunks must be >= 1, "
+                             f"got {chunks}")
+        if chunks > 1 and (not slot or slot[0] % chunks):
+            raise ValueError(
+                f"PallasExec.run: slot row axis {slot[:1]} must "
+                f"divide by chunks={chunks}")
+        dtype = jnp.dtype(dtype)
+        elems = max(1, math.prod(slot))
+        rows, block = self._plan(elems, dtype.itemsize, chunks)
+        key = (rows, block, dtype)
+        call = self._jitted.get(key)
+        if call is None:
+            call = jax.jit(self._build(rows, block, dtype))
+            self._jitted[key] = call
+        return elems, rows, call
+
+    def lower(self, shape, dtype, *, chunks: int = 1, sharding=None):
+        """The kernel launch ``run`` makes for a global buffer of this
+        shape, lowered and not run (``.as_text()``, ``.compile()``)."""
+        _, rows, call = self._prepare(shape, dtype, chunks)
+        return call.lower(jax.ShapeDtypeStruct(
+            (self.nranks, self.num_slots, rows, _LANES), dtype,
+            sharding=sharding))
 
     def run(self, gbuf, *, chunks: int = 1):
         """Execute the whole schedule as ONE Pallas kernel launch.
 
         ``gbuf`` is [nranks, num_slots, *slot] (any array-like; returns
-        jnp).  ``chunks > 1`` requires slot row axis divisible by
-        ``chunks`` and tiles it over the grid (double-buffered block
-        pipeline; bit-identical to ``chunks=1``)."""
+        jnp).  ``chunks > 1`` requires the slot row axis to divide by
+        ``chunks`` and runs at least that many grid steps (double-
+        buffered block pipeline; bit-identical to ``chunks=1``)."""
         gbuf = jnp.asarray(gbuf)
         n, s = self.nranks, self.num_slots
-        if gbuf.shape[:2] != (n, s):
-            raise ValueError(
-                f"PallasExec.run: buffer [{gbuf.shape}] does not match "
-                f"[nranks={n}, num_slots={s}, *slot]")
-        slot = gbuf.shape[2:]
-        if chunks < 1:
-            raise ValueError(f"PallasExec.run: chunks must be >= 1, "
-                             f"got {chunks}")
-        if chunks > 1:
-            if not slot or slot[0] % chunks:
-                raise ValueError(
-                    f"PallasExec.run: slot row axis {slot[:1]} must "
-                    f"divide by chunks={chunks}")
-            c = slot[0]
-            f = int(math.prod(slot[1:])) if len(slot) > 1 else 1
-        else:
-            c = 1
-            f = int(math.prod(slot)) if slot else 1
-        cb = c // chunks
-        key = (c, cb, f, gbuf.dtype)
-        call = self._jitted.get(key)
-        if call is None:
-            call = jax.jit(self._build(c, cb, max(f, 1), gbuf.dtype))
-            self._jitted[key] = call
+        elems, rows, call = self._prepare(gbuf.shape, gbuf.dtype, chunks)
         self.launches += 1
-        out = call(gbuf.reshape(n, s, c, max(f, 1)))
-        return out.reshape((n, s) + slot)
+        flat = gbuf.reshape(n, s, elems)
+        if rows * _LANES != elems:
+            flat = jnp.pad(flat, ((0, 0), (0, 0),
+                                  (0, rows * _LANES - elems)))
+        out = call(flat.reshape(n, s, rows, _LANES))
+        out = out.reshape(n, s, rows * _LANES)[:, :, :elems]
+        return out.reshape((n, s) + gbuf.shape[2:])
 
 
 # ---------------------------------------------------------------------------

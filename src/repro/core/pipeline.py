@@ -20,8 +20,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 
 def gpipe(stage_fn: Callable, params, x_ubatches: jax.Array,
           axis_name: str, *, return_to_first: bool = False) -> jax.Array:
@@ -34,7 +32,7 @@ def gpipe(stage_fn: Callable, params, x_ubatches: jax.Array,
     Returns [M, ub, ...] outputs, valid on the last stage (or stage 0 if
     ``return_to_first``); other stages see zeros.
     """
-    S = compat.axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     M = x_ubatches.shape[0]
     T = M + S - 1

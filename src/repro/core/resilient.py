@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import sys
 import time
 
 import numpy as np
@@ -172,6 +173,14 @@ class DegradationReport:
         path = " -> ".join(f"{a.rung}[{a.outcome}]" for a in self.attempts)
         return (f"{self.schedule}: {path}; recovered_with="
                 f"{self.recovered_with} refit={self.refit_algorithm}")
+
+
+def announce(report: DegradationReport) -> None:
+    """Print a ladder walk that left its first rung: a descent is never
+    silent, even when the result is correct."""
+    if report.degraded:
+        print(f"recovery ladder descended: {report.summary()}",
+              file=sys.stderr, flush=True)
 
 
 class UnrecoverableError(RuntimeError):
@@ -313,6 +322,7 @@ class ResilientExec:
         out = self._run_ladder(buf, report, self.schedule,
                                self.algorithm or self.schedule.name)
         if out is not None:
+            announce(report)
             return out, report
         # every rung failed -> algorithm refit (selector NotApplicable
         # ladder, the PR 8 elastic-swap machinery)
@@ -345,6 +355,7 @@ class ResilientExec:
                 if out is not None:
                     report.refit_algorithm = cand
                     report.recovered_with = child_report.recovered_with
+                    announce(report)
                     return out, report
         raise UnrecoverableError(
             "collective could not be recovered on any transport rung "

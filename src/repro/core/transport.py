@@ -205,7 +205,7 @@ def _flat_rank(axis_names: Sequence[str]):
     """Row-major flattened rank over possibly-multiple mesh axes."""
     idx = jnp.int32(0)
     for name in axis_names:
-        idx = idx * compat.axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx
 
 

@@ -616,15 +616,19 @@ def tune_transport(topo: Topology, *, sizes=DEFAULT_SIZES,
 def select_transport(topo: Topology, nbytes: int, *,
                      policy: str | None = None,
                      table: TunedTable | None = None,
-                     path: str | Path | None = None) -> str:
+                     path: str | Path | None = None,
+                     schedule=None) -> str:
     """Substrate for ``transport="auto"``: "shardmap" or "pallas".
 
     policy "fixed" always returns "shardmap" (the pre-device-side
     default); "tuned" reads the persisted ``TRANSPORT`` winner (falling
     back to the model when no table/section exists); anything else
-    prices both substrates with the launch-aware model."""
+    prices both substrates with the launch-aware model.  Pallas is never
+    picked for a ``schedule`` that does not fit its VMEM bound
+    (``PallasExec.fits``)."""
     if policy == "fixed":
         return "shardmap"
+    name = None
     if policy == "tuned":
         if table is None:
             for fp in (substrate_fingerprint(topo),
@@ -634,12 +638,16 @@ def select_transport(topo: Topology, nbytes: int, *,
                     break
         if table is not None:
             name = table.lookup(TRANSPORT, int(nbytes))
-            if name in _TRANSPORT_CHOICES:
-                return name
-        # no table / no TRANSPORT section: fall through to model pricing
-    times = _transport_times(topo, int(nbytes))["times"]
-    return min(_TRANSPORT_CHOICES, key=lambda k: (times[k],
-                                                  k != "shardmap"))
+    if name not in _TRANSPORT_CHOICES:
+        # no table / no TRANSPORT section: model pricing
+        times = _transport_times(topo, int(nbytes))["times"]
+        name = min(_TRANSPORT_CHOICES, key=lambda k: (times[k],
+                                                      k != "shardmap"))
+    if name == "pallas" and schedule is not None:
+        from repro.core.pallas_lowering import get_pallas_exec
+        if not get_pallas_exec(schedule, topo=topo).fits:
+            return "shardmap"
+    return name
 
 
 def select_overlap_chunks(topo: Topology, nbytes: int, compute_s: float,

@@ -63,16 +63,22 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
         o_ref[0, ...] = (acc[...] / denom[:, None]).astype(o_ref.dtype)
 
 
-def _kernel_gather(idx_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
-                   scale, causal, window, softcap, bq, bk, nk):
+def _kernel_gather(idx_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s,
+                   qg, live_s, *, scale, causal, window, softcap, bq, bk,
+                   nk):
     """Dispatch-gather prologue: the q tile is assembled IN VMEM from a
     token-order q buffer via per-output row indices (``-1`` -> zero
     row) — the terminal gather round of an alltoall-style dispatch
     fused into the attention kernel, so the permuted q tensor never
     materializes in HBM.  Positions (causal/window masks) are
-    output-order.  The row gather uses a traced index vector; on TPU
-    this relies on Mosaic's dynamic-gather lowering (interpret mode —
-    the CI path — models it exactly)."""
+    output-order.
+
+    The gather runs once per q block (first kv step) as a one-hot
+    matmul on the MXU, ``onehot[bq, Sq] @ q[Sq, D]``, into the ``qg``
+    scratch; a dead row's one-hot row is all zero.  Each output row
+    picks exactly one q row, so the product is an exact copy (bf16 q:
+    f32 accumulation; f32 q: HIGHEST precision) as long as q holds no
+    inf/NaN, which a zero one-hot entry would spread."""
     j = pl.program_id(2)    # kv block
     i = pl.program_id(1)    # q block
 
@@ -81,19 +87,27 @@ def _kernel_gather(idx_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
         acc[...] = jnp.zeros_like(acc)
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
+        idx = idx_ref[0]                                # [bq, 1] int32
+        qfull = q_ref[0]                                # [Sq, D]
+        sq = qfull.shape[0]
+        onehot = (idx == jax.lax.broadcasted_iota(jnp.int32, (bq, sq), 1)
+                  ).astype(qfull.dtype)                 # [bq, Sq]
+        prec = (jax.lax.Precision.HIGHEST if qfull.dtype == jnp.float32
+                else None)
+        qg[...] = jax.lax.dot_general(
+            onehot, qfull, (((1,), (0,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32) * scale  # [bq, D]
+        live_s[...] = (idx >= 0).astype(jnp.float32)
 
-    idx = idx_ref[0]                                    # [bq] int32
-    live = idx >= 0
-    qfull = q_ref[0].astype(jnp.float32)                # [Sq, D]
-    q = qfull[jnp.where(live, idx, 0)]                  # [bq, D]
-    q = jnp.where(live[:, None], q, 0.0) * scale
+    live = live_s[...] > 0                              # [bq, 1]
+    q = qg[...]
     k = k_ref[0].astype(jnp.float32)                    # [bk, D]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [bq, bk]
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
     qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = jnp.broadcast_to(live[:, None], (bq, bk))
+    mask = jnp.broadcast_to(live, (bq, bk))
     if causal:
         mask &= kpos <= qpos
     if window is not None:
@@ -116,8 +130,7 @@ def _kernel_gather(idx_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
         # the accumulator holds garbage there; zero it at the write.
         denom = jnp.where(l_s[:, 0] > 0, l_s[:, 0], 1.0)
         out = acc[...] / denom[:, None]
-        o_ref[0, ...] = jnp.where(live[:, None], out,
-                                  0.0).astype(o_ref.dtype)
+        o_ref[0, ...] = jnp.where(live, out, 0.0).astype(o_ref.dtype)
 
 
 def flash_attention_bhsd(q, k, v, *, causal=True, window=None,
@@ -174,18 +187,20 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=None,
         kern,
         grid=grid,
         in_specs=[
-            # idx tile for this q block, shared by the batch's heads
-            pl.BlockSpec((1, bq), lambda h, i, j, nh=nheads: (h // nh, i)),
+            # idx column for this q block, shared by the batch's heads
+            pl.BlockSpec((1, bq, 1), lambda h, i, j, nh=nheads: (h // nh,
+                                                                 i, 0)),
             # the FULL token-order q row buffer for this head
             pl.BlockSpec((1, Sq, D), lambda h, i, j: (h, 0, 0)),
             *kv_specs,
         ],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=scratch + [_vmem((bq, D), jnp.float32),
+                                  _vmem((bq, 1), jnp.float32)],
         compiler_params=_tpu_params(),
         interpret=interpret,
-    )(q_rows.astype(jnp.int32), q, k, v)
+    )(q_rows.astype(jnp.int32)[..., None], q, k, v)
 
 
 def _vmem(shape, dtype):
@@ -194,6 +209,6 @@ def _vmem(shape, dtype):
 
 
 def _tpu_params():
-    from repro.kernels.compat import tpu_compiler_params
-    return tpu_compiler_params(
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))

@@ -18,10 +18,6 @@ from repro.kernels.attention.ref import attention_ref
 from repro.kernels.compat import pallas_interpret
 
 
-def _on_cpu():
-    return jax.default_backend() == "cpu"
-
-
 def _to_bhsd(x):
     B, S, H, D = x.shape
     return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
@@ -53,9 +49,15 @@ def flash_attention(q, k, v, causal=True, window=None, softcap=None,
     into the kernel: output row t attends with token-order q row
     ``q_rows[..., t]`` (``-1`` -> zero output row), so the permuted q of
     an alltoall-style dispatch never materializes in HBM.  Causal /
-    window positions are output-order."""
+    window positions are output-order.  The gather is a one-hot matmul
+    on the MXU, ``[block_q, Sq] @ [Sq, D]`` per q block: about
+    ``2 * Sq * Sq * D`` more flops per head, as much again as QK^T.  It
+    copies rows exactly only while q is finite: an inf or NaN in any q
+    row of a batch turns every gathered row of that batch into NaN
+    (0 * inf)."""
     import os
-    if os.environ.get("REPRO_KERNEL_SURROGATE") == "1" and _on_cpu():
+    if (os.environ.get("REPRO_KERNEL_SURROGATE") == "1"
+            and pallas_interpret()):
         # differentiable surrogate (dry-run): fwd+bwd stream q/k/v/grads
         # once — the flash fwd+bwd kernels' HBM signature
         return _surrogate(q, k, v)

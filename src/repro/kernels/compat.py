@@ -1,42 +1,17 @@
-"""Version-robust Pallas TPU accessors.
+"""The interpret-mode switch shared by every Pallas kernel of the repo.
 
-``pallas.tpu`` renamed ``TPUCompilerParams`` to ``CompilerParams``; the
-kernels build their params through here so they lower on either jax.
-
-``pallas_interpret()`` is the interpret-mode fallback shim: Pallas
+``pallas_interpret()`` is the one place that decides whether Pallas
 kernels (the compute kernels and the device-side ``PallasTransport``
-lowering) ask it whether to run under the Pallas interpreter instead of
-the Mosaic TPU compiler.  ``REPRO_PALLAS_INTERPRET=1`` forces interpret
-mode anywhere (``0`` forces it off); unset, it auto-enables whenever no
-TPU accelerator backs the default jax backend — which is what makes the
-whole kernel surface, transport included, run bit-exact in tier-1 CI on
-CPU-only hosts.
+lowering) run under the Pallas interpreter or through the Mosaic TPU
+compiler: the interpreter exactly when no TPU backs the default backend
+(the CPU test suite, ``JAX_PLATFORMS=cpu``).  No variable overrides it,
+so on a TPU every kernel is compiled.
 """
 from __future__ import annotations
 
-import os
-
-
-def tpu_compiler_params(**kwargs):
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+import jax
 
 
 def pallas_interpret() -> bool:
-    """Should Pallas kernels run in interpret mode here?
-
-    Priority: explicit env override (``REPRO_PALLAS_INTERPRET`` = 1/0),
-    else auto-on when the default backend is not a TPU (CPU CI hosts,
-    GPU hosts without a Mosaic path — the kernels target the TPU
-    lowering, everything else interprets)."""
-    v = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip().lower()
-    if v in ("1", "true", "on", "yes"):
-        return True
-    if v in ("0", "false", "off", "no"):
-        return False
-    import jax
+    """True iff the default backend is not a TPU."""
     return jax.default_backend() != "tpu"

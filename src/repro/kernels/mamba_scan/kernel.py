@@ -92,6 +92,6 @@ def _vmem(shape, dtype):
 
 
 def _tpu_params():
-    from repro.kernels.compat import tpu_compiler_params
-    return tpu_compiler_params(
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"))

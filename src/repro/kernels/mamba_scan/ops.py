@@ -15,12 +15,9 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.compat import pallas_interpret
 from repro.kernels.mamba_scan.kernel import selective_scan_bdt
 from repro.kernels.mamba_scan.ref import selective_scan_ref
-
-
-def _on_cpu():
-    return jax.default_backend() == "cpu"
 
 
 def _surrogate(xc, dt, bmat, cmat, A, D):
@@ -31,7 +28,8 @@ def _surrogate(xc, dt, bmat, cmat, A, D):
 
 
 def selective_scan(xc, dt, bmat, cmat, A, D, block_t=64):
-    if os.environ.get("REPRO_KERNEL_SURROGATE") == "1" and _on_cpu():
+    if (os.environ.get("REPRO_KERNEL_SURROGATE") == "1"
+            and pallas_interpret()):
         # differentiable surrogate: its AD transpose streams the same
         # tensors a fused backward kernel would (inputs + grads once)
         return _surrogate(xc, dt, bmat, cmat, A, D)
@@ -41,7 +39,7 @@ def selective_scan(xc, dt, bmat, cmat, A, D, block_t=64):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _scan_vjp(xc, dt, bmat, cmat, A, D, block_t=64):
     return selective_scan_bdt(xc, dt, bmat, cmat, A, D, block_t=block_t,
-                              interpret=_on_cpu())
+                              interpret=pallas_interpret())
 
 
 def _fwd(xc, dt, bmat, cmat, A, D, block_t):

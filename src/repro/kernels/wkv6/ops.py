@@ -7,18 +7,16 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.compat import pallas_interpret
 from repro.kernels.wkv6.kernel import wkv6_bhtn
 from repro.kernels.wkv6.ref import wkv6_ref
-
-
-def _on_cpu():
-    return jax.default_backend() == "cpu"
 
 
 def wkv6(r, k, v, w, u, block_t=64):
     """r,k,v,w [B,T,H,N]; u [H,N] -> y [B,T,H,N] float32."""
     import os
-    if os.environ.get("REPRO_KERNEL_SURROGATE") == "1" and _on_cpu():
+    if (os.environ.get("REPRO_KERNEL_SURROGATE") == "1"
+            and pallas_interpret()):
         # differentiable HBM-traffic stand-in (dry-run only): fwd+bwd
         # stream inputs/grads once — state stays in VMEM.
         return (r.astype(jnp.float32) * k.astype(jnp.float32)
@@ -32,7 +30,7 @@ def _wkv_vjp(r, k, v, w, u, block_t=64):
     to = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, T, N)
     ub = jnp.broadcast_to(u[None], (B, H, N)).reshape(B * H, N)
     y = wkv6_bhtn(to(r), to(k), to(v), to(w), ub,
-                  block_t=block_t, interpret=_on_cpu())
+                  block_t=block_t, interpret=pallas_interpret())
     return y.reshape(B, H, T, N).transpose(0, 2, 1, 3)
 
 

@@ -17,7 +17,6 @@ import time
 
 import jax
 
-from repro import compat
 from repro.configs import ARCHS, get_config
 from repro.configs.shapes import SHAPES, runnable
 from repro.launch.mesh import make_production_mesh
@@ -124,7 +123,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
         lambda s: NamedSharding(mesh, s), spec_tree,
         is_leaf=lambda x: isinstance(x, P))
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             opts = TrainOptions(**(train_overrides or {}))
             from repro.train.step import state_specs

@@ -15,12 +15,15 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro import compat, configs
 from repro.core import api as mpix_api
 from repro.launch.mesh import make_production_mesh
 from repro.models import model as M
-from repro.serve.step import ServeOptions, jit_decode_step
+from repro.serve.step import (ServeOptions, jit_decode_step, place,
+                              token_spec)
+from repro.train.sharding import data_axes
 
 
 def _run_continuous(args, cfg) -> dict:
@@ -147,6 +150,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0,
                     help="continuous mode: trace seed")
     args = ap.parse_args(argv)
+    compat.enable_compile_cache()
 
     # ---- argument validation (fail loudly, never deep in the loop) ----
     if args.gen < 1:
@@ -199,7 +203,7 @@ def main(argv=None):
     # same pattern train's FaultTolerantLoop uses for signal handlers
     try:
         max_len = args.prompt_len + args.gen
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if args.continuous:
                 return _run_continuous(args, cfg)
 
@@ -232,19 +236,24 @@ def main(argv=None):
             # cache on multi-device meshes (satellite bugfix)
             decode, (pspec, cspec) = jit_decode_step(
                 cfg, mesh, opts, params, cache)
+            params = place(mesh, params, pspec)
+            cache = place(mesh, cache, cspec)
+            tspec = token_spec(mesh, opts)
+            if cross is not None:
+                cross = place(mesh, cross, P(data_axes(mesh)))
 
             # prefill token-by-token through the decode step (keeps one
             # compiled program; the batched-prefill path is exercised by
             # the dry-run and benches)
             t0 = time.time()
-            tok = prompts[:, :1]
+            tok = place(mesh, prompts[:, :1], tspec)
             outs = []
             for i in range(max_len - 1):
                 a = (params, cache, tok) if cfg.encoder is None else \
                     (params, cache, tok, cross)
                 nxt, cache = decode(*a)
                 if i + 1 < args.prompt_len:
-                    tok = prompts[:, i + 1: i + 2]      # teacher-forced
+                    tok = place(mesh, prompts[:, i + 1: i + 2], tspec)
                 else:
                     tok = nxt
                     outs.append(np.asarray(nxt)[:, 0])

@@ -233,6 +233,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    compat.enable_compile_cache()
 
     mpix_api.set_default_policy(args.select_policy)
     cfg, mesh, opts = build(args)
@@ -244,7 +245,7 @@ def main(argv=None):
     pipe = DataPipeline(PipelineConfig(
         vocab_size=cfg.vocab_size, seq_len=args.seq,
         global_batch=args.batch))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn = jax.jit(make_train_step(cfg, mesh, opts))
         state = init_train_state(jax.random.key(0), cfg, opts)
 

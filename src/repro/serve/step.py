@@ -91,20 +91,38 @@ def make_decode_step(cfg, mesh, opts: ServeOptions) -> Callable:
     return decode
 
 
+def _shardings(mesh, spec_tree):
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
+                        is_leaf=lambda x: isinstance(x, P))
+
+
+def place(mesh, tree, spec_tree):
+    """``device_put`` every leaf of ``tree`` to its spec on ``mesh`` —
+    what ``jit_decode_step``'s ``in_shardings`` require of params and
+    cache before the first call."""
+    return jax.device_put(tree, _shardings(mesh, spec_tree))
+
+
+def token_spec(mesh, opts: ServeOptions):
+    """Spec of the [B, 1] token batch ``jit_decode_step`` takes."""
+    return P() if opts.long_context else P(sharding.data_axes(mesh))
+
+
 def jit_decode_step(cfg, mesh, opts: ServeOptions, params, cache):
+    """jit the decode step with params/cache/token shardings; returns
+    (step, (param_specs, cache_specs)).  Place params, cache and tokens
+    with ``place`` (tokens on ``token_spec``) before each call."""
     pspec = sharding.param_specs(params, cfg, mesh)
     cspec = sharding.cache_specs(cache, cfg, mesh,
                                  long_context=opts.long_context)
     d_axes = sharding.data_axes(mesh)
-    tok_spec = P() if opts.long_context else P(d_axes)
-    to_sh = lambda spec: jax.tree.map(
-        lambda s: NamedSharding(mesh, s), spec,
-        is_leaf=lambda x: isinstance(x, P))
+    tok_spec = token_spec(mesh, opts)
     step = make_decode_step(cfg, mesh, opts)
-    in_sh = [to_sh(pspec), to_sh(cspec), NamedSharding(mesh, tok_spec)]
+    in_sh = [_shardings(mesh, pspec), _shardings(mesh, cspec),
+             NamedSharding(mesh, tok_spec)]
     if cfg.encoder is not None:
         in_sh.append(NamedSharding(mesh, P(d_axes)))
     return jax.jit(step,
                    in_shardings=tuple(in_sh),
                    out_shardings=(NamedSharding(mesh, tok_spec),
-                                  to_sh(cspec))), (pspec, cspec)
+                                  _shardings(mesh, cspec))), (pspec, cspec)

@@ -172,11 +172,11 @@ def _dispatch_overlapped(send, w_gate, w_up, w_down, *, chunks: int,
 def _dispatch_body(rp, w_gate, w_up, w_down, x, *, cfg: MoEConfig,
                    ep, opts: EPOptions, act):
     B, S, d = x.shape
-    M = compat.axis_size("model")
+    M = jax.lax.axis_size("model")
     m = jax.lax.axis_index("model")
     N_ep = 1
     for a in ep:
-        N_ep *= compat.axis_size(a)
+        N_ep *= jax.lax.axis_size(a)
     E, K = cfg.n_experts, cfg.top_k
     E_loc = E // N_ep
     T_total = B * S

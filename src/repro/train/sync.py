@@ -23,25 +23,44 @@ import jax.numpy as jnp
 from repro.core import api as mpix
 from repro.optim.compress import compress_int8, decompress_int8
 
-from repro import compat
+
+# lanes per row of the flattened gradient.  Every sync path moves the
+# gradient as one 2-D [rows, _ROW] f32 array: XLA's TPU compiler takes
+# minutes over one 1-D concatenation of every leaf at model size, and
+# seconds over the same bytes in rows.
+_ROW = 1024
 
 
-def _flatten(tree):
+def _flatten(tree, mult: int):
+    """Every leaf as f32 rows of ``_ROW`` (zero padded), concatenated:
+    [R, _ROW] with R a multiple of ``mult``."""
     leaves, tdef = jax.tree.flatten(tree)
-    sizes = [l.size for l in leaves]
-    flat = jnp.concatenate([l.reshape(-1).astype(jnp.float32)
-                            for l in leaves])
+    rows = []
+    for l in leaves:
+        f = l.reshape(-1).astype(jnp.float32)
+        rows.append(jnp.pad(f, (0, (-f.size) % _ROW)).reshape(-1, _ROW))
+    flat = jnp.concatenate(rows)
+    flat = jnp.pad(flat, ((0, (-flat.shape[0]) % mult), (0, 0)))
     return flat, (tdef, [l.shape for l in leaves],
-                  [l.dtype for l in leaves], sizes)
+                  [l.dtype for l in leaves], [l.size for l in leaves])
 
 
 def _unflatten(flat, meta):
     tdef, shapes, dtypes, sizes = meta
     out, off = [], 0
     for shp, dt, sz in zip(shapes, dtypes, sizes):
-        out.append(flat[off: off + sz].reshape(shp).astype(dt))
-        off += sz
+        n = -(-sz // _ROW)
+        out.append(flat[off: off + n].reshape(-1)[:sz].reshape(shp)
+                   .astype(dt))
+        off += n
     return jax.tree.unflatten(tdef, out)
+
+
+def _axes_size(names) -> int:
+    n = 1
+    for a in names:
+        n *= jax.lax.axis_size(a)
+    return n
 
 
 def dp_allreduce(grads, axis_names, *, algorithm="xla", buckets=1,
@@ -54,22 +73,19 @@ def dp_allreduce(grads, axis_names, *, algorithm="xla", buckets=1,
     arms the api recovery ladder for each bucket's collective."""
     names = (axis_names,) if isinstance(axis_names, str) \
         else tuple(axis_names)
+    n = _axes_size(names)
     if denom is None:
-        denom = 1
-        for a in names:
-            denom *= compat.axis_size(a)
-    flat, meta = _flatten(grads)
-    per = -(-flat.size // max(1, buckets))
-    pad = per * max(1, buckets) - flat.size
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    parts = flat.reshape(max(1, buckets), per)
+        denom = n
+    buckets = max(1, buckets)
+    # each bucket's rows divide by n: the allreduce cuts its chunks
+    # along the row axis
+    flat, meta = _flatten(grads, buckets * n)
+    parts = flat.reshape((buckets, -1, _ROW))
     done = [mpix.mpix_allreduce(parts[i], names, algorithm=algorithm,
                                 transport=transport,
                                 resilience=resilience)
-            for i in range(parts.shape[0])]
-    flat = jnp.concatenate(done)[: sum(meta[3])] / denom
-    return _unflatten(flat, meta)
+            for i in range(buckets)]
+    return _unflatten(jnp.concatenate(done) / denom, meta)
 
 
 # dp_algorithm (allreduce registry) -> its (reduce_scatter, allgather)
@@ -104,19 +120,12 @@ def dp_allreduce_overlap(grads, axis_names, *, algorithm="xla",
     if chunks < 1:
         raise ValueError(
             f"dp_allreduce_overlap: chunks must be >= 1, got {chunks}")
-    n = 1
-    for a in names:
-        n *= compat.axis_size(a)
+    n = _axes_size(names)
     if denom is None:
         denom = n
-    flat, meta = _flatten(grads)
-    total = flat.size
-    # each chunk pads to a multiple of n so the scatter dim divides
-    per = -(-(-(-total // chunks)) // n) * n
-    pad = per * chunks - total
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    parts = flat.reshape(chunks, per)
+    # each chunk's rows divide by n so the scatter dim divides
+    flat, meta = _flatten(grads, chunks * n)
+    parts = flat.reshape((chunks, -1, _ROW))
     rs_alg, ag_alg = _RS_AG.get(algorithm, (algorithm, algorithm))
     shards = []
     gsq = jnp.float32(0)
@@ -135,8 +144,7 @@ def dp_allreduce_overlap(grads, axis_names, *, algorithm="xla",
                                 transport=transport,
                                 resilience=resilience)
             for sh in shards]
-    flat = jnp.concatenate(outs)[: total]
-    return _unflatten(flat, meta), gnorm
+    return _unflatten(jnp.concatenate(outs), meta), gnorm
 
 
 def dp_allreduce_compressed(grads, residual, *, intra_algorithm="xla",
@@ -151,16 +159,17 @@ def dp_allreduce_compressed(grads, residual, *, intra_algorithm="xla",
       4. divide by ``denom`` (global live-token count).
     Returns (synced grads, new residual).
     """
-    Q = compat.axis_size("pod")
+    Q = jax.lax.axis_size("pod")
+    D = jax.lax.axis_size("data")
     if denom is None:
-        denom = Q * compat.axis_size("data")
-    flat, meta = _flatten(grads)
+        denom = Q * D
+    flat, meta = _flatten(grads, D)
     flat = mpix.mpix_allreduce(flat, "data", algorithm=intra_algorithm,
                                resilience=resilience)
     if residual is None:
         res_flat = jnp.zeros_like(flat)
     else:
-        res_flat, _ = _flatten(residual)
+        res_flat, _ = _flatten(residual, D)
     x = flat + res_flat
     q, s = compress_int8(x)
     sent = decompress_int8(q, s, x.shape, jnp.float32)

@@ -47,7 +47,7 @@ def allgather_under(resilience):
                                      resilience=resilience),
         mesh=mesh, in_specs=P("data"), out_specs=P(None),
         check_vma=False))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return np.asarray(f(x))
 
 
@@ -120,7 +120,7 @@ def allreduce_under(resilience):
                                      resilience=resilience),
         mesh=mesh, in_specs=P("data"), out_specs=P(None),
         check_vma=False))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return np.asarray(f(x))
 
 

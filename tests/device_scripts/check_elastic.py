@@ -29,7 +29,7 @@ pipe = PipelineConfig(vocab_size=cfg.vocab_size, seq_len=16,
 def run(mesh, state, steps, start):
     dp = DataPipeline(pipe)
     step_fn = jax.jit(make_train_step(cfg, mesh, opts))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = jax.device_put(state)
         for s in range(start, start + steps):
             b = jax.device_put(

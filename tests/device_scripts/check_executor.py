@@ -49,19 +49,19 @@ f = jax.jit(compat.shard_map(
 
 x32 = np.random.default_rng(0).normal(
     size=(N * sched.num_slots, 4)).astype(np.float32)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for _ in range(4):
         jax.block_until_ready(f(x32))
 assert ex.trace_count == 1, f"expected 1 trace after 4 calls, got {ex.trace_count}"
 
-with compat.set_mesh(mesh):                       # new dtype: one more trace
+with jax.set_mesh(mesh):                       # new dtype: one more trace
     for _ in range(3):
         jax.block_until_ready(f(x32.astype(jnp.bfloat16)))
 assert ex.trace_count == 2, ex.trace_count
 
 x_wide = np.random.default_rng(1).normal(
     size=(N * sched.num_slots, 6)).astype(np.float32)
-with compat.set_mesh(mesh):                       # new slot shape: one more
+with jax.set_mesh(mesh):                       # new slot shape: one more
     jax.block_until_ready(f(x_wide))
     jax.block_until_ready(f(x_wide))
 assert ex.trace_count == 3, ex.trace_count
@@ -70,7 +70,7 @@ print(f"trace counts ok: 9 calls -> {ex.trace_count} traces "
 
 # --- 1b. topology-armed executor: baked where-masks add no retraces --------
 # the armed compilation bakes scratch-safe indices AND jnp.where masks
-# as device constants (executor._ExecRound.jnp_tables); repeated jitted
+# as numpy tables (executor._ExecRound.tables); repeated jitted
 # calls of the armed executor must still lower exactly once, and the
 # armed executor is a distinct cache entry from the topology-free one
 topo2 = Topology(8, 4)
@@ -81,21 +81,21 @@ tr_armed = ShardMapTransport(N, ("data",), topo=topo2)
 fa = jax.jit(compat.shard_map(
     lambda b: tr_armed.run(sched, b), mesh=mesh,
     in_specs=P("data"), out_specs=P("data"), check_vma=False))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for _ in range(5):
         jax.block_until_ready(fa(x32))
 assert ex_armed.trace_count == 1, (
     f"baked masks must not retrace: 5 calls -> {ex_armed.trace_count}")
 want = SimTransport(N).run_reference(
     sched, x32.reshape(N, sched.num_slots, 4))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = np.asarray(fa(x32))
 assert np.array_equal(want.reshape(got.shape), got)
-# the mask/index device constants are materialized once and reused
-tables0 = [r.jnp_tables() for r in ex_armed._rounds]
-tables1 = [r.jnp_tables() for r in ex_armed._rounds]
+# the mask/index tables are materialized once and reused
+tables0 = [r.tables() for r in ex_armed._rounds]
+tables1 = [r.tables() for r in ex_armed._rounds]
 assert all(a is b for ta, tb in zip(tables0, tables1)
-           for a, b in zip(ta, tb)), "jnp tables/masks must bake once"
+           for a, b in zip(ta, tb)), "tables/masks must bake once"
 print(f"armed executor: 5 calls -> {ex_armed.trace_count} trace, "
       f"distinct cache entry, masks baked once, bit-exact")
 
@@ -106,7 +106,7 @@ g = jax.jit(compat.shard_map(
     lambda v: api.mpix_allgather(v, "data", algorithm="ring"),
     mesh=mesh, in_specs=P("data"), out_specs=P(None), check_vma=False))
 xs = np.random.default_rng(2).normal(size=(N * 4, 3)).astype(np.float32)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for _ in range(3):
         jax.block_until_ready(g(xs))
 stats = executor.cache_stats()
@@ -140,7 +140,7 @@ tr_n = ShardMapTransport(N, ("data",))
 fn = jax.jit(compat.shard_map(
     lambda b: tr_n.run(naive, b), mesh=mesh,
     in_specs=P("data"), out_specs=P("data"), check_vma=False))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     got_naive = np.asarray(fn(xbuf.reshape(N * N, 2)))
 assert np.array_equal(want_naive.reshape(got_naive.shape), got_naive)
 print(f"fusion win on staged multi-pod schedule: "
@@ -178,7 +178,7 @@ want8 = run_sim(plan8, vals)
 h = jax.jit(compat.shard_map(
     lambda v: run_shardmap(plan8, v, ("data",)), mesh=mesh,
     in_specs=P("data"), out_specs=P("data"), check_vma=False))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     got8 = np.asarray(h(np.concatenate(vals, axis=0)))
 got8 = got8.reshape(N, -1, 2)
 for r in range(N):

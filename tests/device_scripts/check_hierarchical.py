@@ -49,7 +49,7 @@ def shardmap_run(sched, x):
     f = jax.jit(compat.shard_map(
         lambda b: tr.run(sched, b), mesh=MESH,
         in_specs=P(AXES), out_specs=P(AXES), check_vma=False))
-    with compat.set_mesh(MESH):
+    with jax.set_mesh(MESH):
         got = np.asarray(f(x.reshape(N * sched.num_slots, FEAT)))
     return got.reshape(N, sched.num_slots, FEAT)
 

@@ -36,7 +36,7 @@ for mesh_name, (mesh, axes, rpp) in MESHES.items():
             mesh=mesh, in_specs=P(tuple(axes)), out_specs=P(tuple(axes)),
             check_vma=False))
         stacked = np.stack(values).reshape((N * N_LOCAL, FEAT))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = np.asarray(f(stacked))
         got = got.reshape(N, -1, FEAT)
         ok = all(np.allclose(got[r, : plan.recv_sizes[r]], want[r],

@@ -55,7 +55,7 @@ def _runner(fn):
     f = jax.jit(compat.shard_map(
         fn, mesh=mesh1d, in_specs=P("data"), out_specs=P("data"),
         check_vma=False))
-    with compat.set_mesh(mesh1d):
+    with jax.set_mesh(mesh1d):
         return np.asarray(f(buf.reshape((N * sched.num_slots, 8, 3))))
 
 
@@ -87,7 +87,7 @@ def _a2a(algo):
         lambda v: mpix.mpix_alltoall(v, "data", algorithm=algo),
         mesh=mesh1d, in_specs=P("data"), out_specs=P("data"),
         check_vma=False))
-    with compat.set_mesh(mesh1d):
+    with jax.set_mesh(mesh1d):
         return np.asarray(f(xa))
 
 
@@ -106,7 +106,7 @@ def _a2a_overlap(algo, chunks):
             chunks=chunks, algorithm=algo).reshape(N * 6, 5),
         mesh=mesh1d, in_specs=P("data"), out_specs=P("data"),
         check_vma=False))
-    with compat.set_mesh(mesh1d):
+    with jax.set_mesh(mesh1d):
         return np.asarray(f(xa))
 
 
@@ -134,7 +134,7 @@ for ov in (None, 2, 4, 0):
                         capacity_factor=float(mcfg.n_experts),
                         overlap_chunks=ov),
         cfg.mlp_act)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         outs[ov] = np.asarray(jax.jit(
             lambda pp, xx: disp(pp, mcfg, xx))(p, xm), np.float32)
 for ov in (2, 4, 0):
@@ -158,7 +158,7 @@ from jax.sharding import NamedSharding
 
 results = {}
 for tag, opts in (("base", base_opts), ("overlap", over_opts)):
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         bsh = jax.device_put(batch, NamedSharding(mesh, P(("data",))))
         new, m = jax.jit(make_train_step(cfg_t, mesh, opts))(
             jax.device_put(state), bsh)
@@ -187,7 +187,7 @@ for tag, sopts in (
             alltoall="pairwise",
             capacity_factor=float(mcfg.n_experts),
             overlap_chunks=2)))):
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits[tag] = np.asarray(jax.jit(
             make_prefill_step(cfg, mesh, sopts))(params, sbatch),
             np.float32)

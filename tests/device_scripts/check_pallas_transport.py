@@ -46,7 +46,7 @@ def run(mesh, axes, fn, x, out_spec=None):
     f = jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=spec,
                                  out_specs=out_spec or spec,
                                  check_vma=False))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return np.asarray(f(x))
 
 

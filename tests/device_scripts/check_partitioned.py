@@ -41,7 +41,7 @@ for parts in (1, 2, 4, 8):
         lambda v, p=parts: pc.partitioned_ppermute(v, "data", perm, p),
         mesh=mesh, in_specs=P("data"), out_specs=P("data"),
         check_vma=False))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         outs[parts] = np.asarray(f(x))
 want = x.reshape(N, 16, 4)[np.array([(i - 1) % N for i in range(N)])]
 check("partitioned_ppermute matches shift", np.allclose(
@@ -53,7 +53,7 @@ for parts in (2, 4, 8):
 # claim 1 structural check: the 1-partition pipeline lowers to the same
 # number of collective-permute ops as the monolithic ppermute
 def _n_cp(fn):
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         hlo = jax.jit(fn).lower(x).compile().as_text()
     return len(re.findall(r"= \S* ?collective-permute", hlo))
 
@@ -73,7 +73,7 @@ f = jax.jit(compat.shard_map(
         consume=lambda c, chunk: c + chunk.sum(0),
         init=jnp.zeros((4,), jnp.float32)),
     mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = np.asarray(f(x))
 check("early-bird consume == sum of received shard",
       np.allclose(got.reshape(N, 4), want.sum(1), atol=1e-4))
@@ -85,7 +85,7 @@ f = jax.jit(compat.shard_map(
     lambda v, ww: pc.allgather_matmul(v, ww, "data"),
     mesh=mesh, in_specs=(P("data"), P()), out_specs=P(),
     check_vma=False))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = np.asarray(f(xg, w))
 check("allgather_matmul == all_gather(x) @ w",
       np.allclose(got, xg @ w, atol=1e-4))
@@ -99,7 +99,7 @@ f = jax.jit(compat.shard_map(
     in_specs=(P(None, "data"), P("data")), out_specs=P("data"),
     check_vma=False))
 # inside: each rank has x_local [m, k/N] and w_local [k/N, 10]
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = np.asarray(f(xr, wr))       # [m, 10] scattered over ranks
 check("matmul_reduce_scatter == psum_scatter(x @ w)",
       np.allclose(got, xr @ wr, atol=1e-3))
@@ -110,7 +110,7 @@ tree = {"a": rng.normal(size=(N, 33)).astype(np.float32),
 f = jax.jit(compat.shard_map(
     lambda t: pc.bucketed_psum(t, "data", buckets=3),
     mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = f(tree)
 check("bucketed_psum == tree psum",
       np.allclose(got["a"], tree["a"].sum(0, keepdims=True), atol=1e-4)
@@ -133,7 +133,7 @@ f = jax.jit(compat.shard_map(
                              return_to_first=True),
     mesh=mesh, in_specs=(P("data"), P("data"), P()),
     out_specs=P(), check_vma=False))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = np.asarray(f(Ws, bs, xs))
 h = xs
 for s in range(S):
@@ -160,7 +160,7 @@ def loss_seq(v):
     return h.sum()
 
 
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     g_pipe = np.asarray(jax.jit(jax.grad(loss_pipe))(xs))
 g_seq = np.asarray(jax.grad(loss_seq)(xs))
 check("gpipe reverse-mode AD == sequential grad",

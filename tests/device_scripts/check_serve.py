@@ -23,7 +23,8 @@ from repro.core import kvtransfer
 from repro.core.topology import Topology
 from repro.models import model as M
 from repro.serve.engine import ContinuousBatchingEngine, EngineConfig
-from repro.serve.step import ServeOptions, jit_decode_step
+from repro.serve.step import (ServeOptions, jit_decode_step, place,
+                              token_spec)
 from repro.serve.traffic import poisson_workload, run_workload
 
 failures = []
@@ -31,12 +32,15 @@ failures = []
 # ---- 1. decode-step cache shardings on the 8-device mesh -----------------
 cfg = configs.get_smoke("smollm-360m")
 mesh = compat.make_mesh((4, 2), ("data", "model"))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     params = M.init_params(jax.random.key(0), cfg)
     cache = M.init_cache(cfg, 4, 8)
     decode, (pspec, cspec) = jit_decode_step(
         cfg, mesh, ServeOptions(), params, cache)
-    tok = jax.numpy.zeros((4, 1), jax.numpy.int32)
+    params = place(mesh, params, pspec)
+    cache = place(mesh, cache, cspec)
+    tok = place(mesh, jax.numpy.zeros((4, 1), jax.numpy.int32),
+                token_spec(mesh, ServeOptions()))
     nxt, cache2 = decode(params, cache, tok)
     jax.block_until_ready(nxt)
 

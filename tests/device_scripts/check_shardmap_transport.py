@@ -45,7 +45,7 @@ def check(mesh_name, mesh, axes, coll, algo):
         f = jax.jit(compat.shard_map(
             lambda v: api.mpix_allgather(v, axes, algorithm=algo),
             mesh=mesh, in_specs=spec, out_specs=P(None), check_vma=False))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = np.asarray(f(x))
         return np.allclose(got, x)
     if coll == "allreduce":
@@ -53,7 +53,7 @@ def check(mesh_name, mesh, axes, coll, algo):
         f = jax.jit(compat.shard_map(
             lambda v: api.mpix_allreduce(v, axes, algorithm=algo),
             mesh=mesh, in_specs=spec, out_specs=P(None), check_vma=False))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = np.asarray(f(x))
         return np.allclose(got, x.reshape(N, 4, 6).sum(0), atol=1e-4)
     if coll == "reduce_scatter":
@@ -63,7 +63,7 @@ def check(mesh_name, mesh, axes, coll, algo):
         f = jax.jit(compat.shard_map(
             lambda v: api.mpix_reduce_scatter(v, axes, algorithm=algo),
             mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = np.asarray(f(x))  # rank r returns reduced row r -> [N, 6]
         want = x.reshape(N, N, 6).sum(0)  # row r fully reduced
         return np.allclose(got, want, atol=1e-4)
@@ -72,7 +72,7 @@ def check(mesh_name, mesh, axes, coll, algo):
         f = jax.jit(compat.shard_map(
             lambda v: api.mpix_alltoall(v, axes, algorithm=algo),
             mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = np.asarray(f(x))
         want = x.reshape(N, N, 6).swapaxes(0, 1).reshape(N * N, 6)
         return np.allclose(got, want, atol=1e-5)
@@ -87,6 +87,38 @@ for mesh_name, (mesh, axes) in MESHES.items():
                 failures.append((mesh_name, coll, algo))
             print(f"{mesh_name:5s} {coll:15s} {algo:28s} "
                   f"{'ok' if ok else 'FAIL'}")
+
+# mpix_allreduce zero pads a leading dim that N does not divide and cuts
+# its chunks along it: bitwise the run_reference of that layout
+from repro.core.algorithms import REGISTRY
+from repro.core.topology import Topology
+from repro.core.transport import SimTransport
+
+mesh, axes = MESHES["flat"]
+topo = Topology(N, N)
+for algo in ("ring_rs_ag", "recursive_halving_doubling"):
+    sched = REGISTRY["allreduce"][algo](topo)
+    for shape in ((13,), (3, 5), (5, 2, 3)):
+        xr = rng.normal(size=(N,) + shape).astype(np.float32)
+        f = jax.jit(compat.shard_map(
+            lambda v, a=algo: api.mpix_allreduce(v, axes, algorithm=a,
+                                                 topo=topo),
+            mesh=mesh, in_specs=P(axes), out_specs=P(axes)))
+        with jax.set_mesh(mesh):
+            got = np.asarray(f(xr.reshape((N * shape[0],) + shape[1:])))
+        lead = -(-shape[0] // N) * N
+        pad = np.zeros((N, lead) + shape[1:], np.float32)
+        pad[:, : shape[0]] = xr
+        ref = SimTransport(N).run_reference(
+            sched, pad.reshape((N, N, -1) + shape[1:]))
+        want = ref.reshape((N, lead) + shape[1:])[:, : shape[0]]
+        ok = (np.array_equal(got.reshape(want.shape).view(np.uint32),
+                             want.view(np.uint32))
+              and np.allclose(want[0], xr.sum(0), atol=1e-4))
+        if not ok:
+            failures.append(("flat", "allreduce padded", algo, shape))
+        print(f"flat  allreduce padded  {algo:28s} {shape} "
+              f"{'ok' if ok else 'FAIL'}")
 
 if failures:
     raise SystemExit(f"FAILURES: {failures}")

@@ -51,7 +51,7 @@ for mesh in (mesh_flat, mesh_pods):
             mesh, EPOptions(alltoall=algo,
                             capacity_factor=float(mcfg.n_experts)),
             cfg.mlp_act)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = np.asarray(jax.jit(lambda pp, xx: disp(pp, mcfg, xx))(
                 p, x), np.float32)
         ok = np.allclose(got, want, atol=2e-2, rtol=2e-2)
@@ -82,7 +82,7 @@ for mesh, algos in ((mesh_flat, ["xla", "ring_rs_ag", "hierarchical"]),
                             remat=False, peak_lr=1e-3, warmup_steps=1,
                             total_steps=100)
         step = make_train_step(cfg, mesh, opts)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             bsh = jax.device_put(batch, NamedSharding(mesh, P(d_axes)))
             st = jax.device_put(state0)
             new, m = jax.jit(step)(st, bsh)
@@ -95,7 +95,7 @@ for mesh, algos in ((mesh_flat, ["xla", "ring_rs_ag", "hierarchical"]),
 opts = TrainOptions(dp_mode="explicit", dp_algorithm="ring_rs_ag",
                     grad_buckets=4, remat=False, peak_lr=1e-3,
                     warmup_steps=1, total_steps=100)
-with compat.set_mesh(mesh_flat):
+with jax.set_mesh(mesh_flat):
     bsh = jax.device_put(batch, NamedSharding(mesh_flat, P(("data",))))
     new, m = jax.jit(make_train_step(cfg, mesh_flat, opts))(
         jax.device_put(state0), bsh)
@@ -108,7 +108,7 @@ check("bucketed explicit DP == 1-dev",
 opts = TrainOptions(dp_mode="explicit", compress_dcn=True, remat=False,
                     peak_lr=1e-3, warmup_steps=1, total_steps=100)
 state_c = init_train_state(jax.random.key(0), cfg, opts)
-with compat.set_mesh(mesh_pods):
+with jax.set_mesh(mesh_pods):
     bsh = jax.device_put(batch,
                          NamedSharding(mesh_pods, P(("pod", "data"))))
     new, m = jax.jit(make_train_step(cfg, mesh_pods, opts))(
@@ -121,7 +121,7 @@ check("compressed DCN sync finite + close",
 from repro.train.step import jit_train_step
 opts = TrainOptions(dp_mode="fsdp", remat=True, peak_lr=1e-3,
                     warmup_steps=1, total_steps=100)
-with compat.set_mesh(mesh_flat):
+with jax.set_mesh(mesh_flat):
     bspec = jax.tree.map(lambda _: P(("data",)), batch)
     step, sspec = jit_train_step(cfg, mesh_flat, opts,
                                  state0, bspec)

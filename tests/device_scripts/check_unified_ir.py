@@ -55,7 +55,7 @@ def bit_exact(sched, mesh, axes, dtype) -> bool:
     f = jax.jit(compat.shard_map(
         lambda b: tr.run(sched, b), mesh=mesh,
         in_specs=P(axes), out_specs=P(axes), check_vma=False))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = np.asarray(f(x.reshape(N * sched.num_slots, FEAT)))
     return np.array_equal(want.reshape(got.shape), got)
 

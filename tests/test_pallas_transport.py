@@ -37,7 +37,8 @@ from repro.core.algorithms import REGISTRY
 from repro.core.pallas_lowering import get_pallas_exec
 from repro.core.schedule import NotApplicable
 from repro.core.topology import Topology, flat_topology, torus_topology
-from repro.core.transport import PallasTransport, SimTransport
+from repro.core.transport import (PallasTransport, SimTransport,
+                                  TransportError)
 
 
 @pytest.fixture(autouse=True)
@@ -183,6 +184,25 @@ def test_auto_transport_resolves_to_valid_choice():
         assert kind in ("shardmap", "pallas")
 
 
+def test_pallas_past_the_vmem_bound_is_typed_and_never_auto(monkeypatch):
+    """A schedule whose slot blocks cannot each hold one (8, 128) tile
+    inside ``VMEM_BUDGET`` raises a ``TransportError`` naming the bound,
+    and ``transport="auto"`` runs that schedule on shardmap."""
+    topo = TOPOS["flat"]
+    sched = REGISTRY["allgather"]["ring"](topo)
+    pex = get_pallas_exec(sched, topo=topo)
+    assert pex.fits
+    assert mpix._resolve_transport("auto", topo, 1024, policy="model",
+                                   schedule=sched) == "pallas"
+    monkeypatch.setattr(pallas_lowering, "VMEM_BUDGET",
+                        pex.min_vmem_bytes - 1)
+    assert not pex.fits
+    with pytest.raises(TransportError, match="VMEM_BUDGET"):
+        pex.run(np.zeros((topo.nranks, sched.num_slots, 8), np.float32))
+    assert mpix._resolve_transport("auto", topo, 1024, policy="model",
+                                   schedule=sched) == "shardmap"
+
+
 # ---------------------------------------------------------------------------
 # compute-fused terminal rounds
 # ---------------------------------------------------------------------------
@@ -256,10 +276,13 @@ def test_attention_gather_prologue_matches_reference():
 
 
 def test_interpret_shim_env_override(monkeypatch):
+    """No variable overrides the interpret decision: the interpreter
+    runs exactly when no TPU backs the default backend, so a TPU run
+    always compiles its kernels."""
     from repro.kernels.compat import pallas_interpret
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert pallas_interpret() is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert pallas_interpret() is False
+    want = jax.default_backend() != "tpu"
+    for v in ("1", "0"):
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", v)
+        assert pallas_interpret() is want
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
-    assert pallas_interpret() == (jax.default_backend() != "tpu")
+    assert pallas_interpret() is want

@@ -50,7 +50,7 @@ def test_gpipe_single_stage_matches_sequential():
     f = jax.jit(compat.shard_map(
         lambda v: pl.gpipe(stage_fn, (W, b), v, "data"),
         mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = np.asarray(f(xs))
     want = np.tanh(xs @ W + b)
     np.testing.assert_allclose(got, want, atol=1e-5)

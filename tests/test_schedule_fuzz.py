@@ -201,7 +201,7 @@ def check_fingerprint_roundtrip(sched: CommSchedule) -> None:
     # any table mutation must change the identity
     rnd = sched.rounds[0]
     g = rnd.gather_idx.copy()
-    g[0, 0] = (g[0, 0] + 2) % sched.num_slots   # stays a legal index
+    g[0, 0] = (g[0, 0] + 1) % sched.num_slots   # legal, and always moves
     mutated = dataclasses.replace(
         sched,
         rounds=(dataclasses.replace(rnd, gather_idx=g),) + sched.rounds[1:])

@@ -223,3 +223,51 @@ def test_remesh_plan():
     with pytest.raises(ValueError):
         remesh_plan(global_batch=256, old_devices=512, new_devices=384,
                     data_axis_size=24)
+
+
+# ---------------------------------------------------------------------------
+# persistent compile cache (repro.compat.enable_compile_cache)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("env_dir", [True, False], ids=["env", "fixed"])
+def test_compile_cache_lands_in_one_directory(tmp_path, monkeypatch,
+                                              env_dir):
+    """With ``JAX_COMPILATION_CACHE_DIR`` set the cache lands there and
+    nowhere else; without it, at the fixed ``<checkout>/.jax_cache``."""
+    from pathlib import Path
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro import compat
+
+    fixed = Path(compat.__file__).resolve().parents[2] / ".jax_cache"
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_enable_compilation_cache")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        compilation_cache.reset_cache()
+        path = compat.enable_compile_cache()
+        want = tmp_path if env_dir else fixed
+        assert Path(path) == want
+        assert Path(jax.config.jax_compilation_cache_dir) == want
+        if env_dir:
+            # cache even this tiny program, then look where it went
+            jax.config.update("jax_enable_compilation_cache", True)
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 0)
+            jax.config.update(
+                "jax_persistent_cache_min_entry_size_bytes", -1)
+            seed = float(np.random.default_rng().integers(1 << 30))
+            jax.jit(lambda x: x * seed + 1.0)(jnp.ones(3)).block_until_ready()
+            assert any(tmp_path.iterdir()), "nothing cached in the env dir"
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
