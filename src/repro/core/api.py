@@ -239,6 +239,16 @@ def take_degradations() -> list:
     return out
 
 
+def _attempt(collective: str, run, kind: str, algo: str):
+    """``run(kind, algo)`` under the named scope that says what it
+    executes: ``mpix.<collective>.<algorithm>.<transport>``, or
+    ``mpix.<collective>.xla`` for the native lowering."""
+    scope = (f"mpix.{collective}.xla" if algo == "xla"
+             else f"mpix.{collective}.{algo}.{kind}")
+    with jax.named_scope(scope):
+        return run(kind, algo)
+
+
 def _execute(collective: str, run, *, algorithm: str, policy,
              topo: Topology, nbytes: int, transport: str, resilience,
              xla_ok: bool = True, schedule=None):
@@ -271,12 +281,12 @@ def _execute(collective: str, run, *, algorithm: str, policy,
                                     policy=policy or _DEFAULT_POLICY)
     opts = resolve_resilience(resilience)
     if algorithm == "xla":
-        return run("xla", "xla")
+        return _attempt(collective, run, "xla", "xla")
     if transport == "auto" and schedule is None:
         schedule = _schedule(collective, algorithm, topo)
     kind = _resolve_transport(transport, topo, nbytes, policy, schedule)
     if opts is None:
-        return run(kind, algorithm)
+        return _attempt(collective, run, kind, algorithm)
 
     report = DegradationReport(schedule=f"{collective}.{algorithm}",
                                verify="off")
@@ -294,7 +304,7 @@ def _execute(collective: str, run, *, algorithm: str, policy,
         for attempt in range(opts.max_retries + 1):
             t0 = time.perf_counter()
             try:
-                out = run(k, algorithm)
+                out = _attempt(collective, run, k, algorithm)
             except TransportError as e:
                 report.attempts.append(Attempt(
                     rung=k, algorithm=algorithm, attempt=attempt,
@@ -323,7 +333,7 @@ def _execute(collective: str, run, *, algorithm: str, policy,
                    if a != algorithm and a not in ladder]
         for cand in ladder:
             try:
-                out = run(kinds[0], cand)
+                out = _attempt(collective, run, kinds[0], cand)
             except (TransportError, NotApplicable) as e:
                 report.attempts.append(Attempt(
                     rung="refit", algorithm=cand, attempt=0,
@@ -336,7 +346,7 @@ def _execute(collective: str, run, *, algorithm: str, policy,
             return finish(out, kinds[0])
     if xla_ok:
         try:
-            out = run("xla", "xla")
+            out = _attempt(collective, run, "xla", "xla")
         except Exception as e:  # native lowering is best-effort terminal
             report.attempts.append(Attempt(
                 rung="xla", algorithm="xla", attempt=0,

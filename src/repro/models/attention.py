@@ -161,22 +161,29 @@ def init_cache(cfg: AttnConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
 
 
 def decode_step(p, cfg: AttnConfig, x, cache, *, window=None, eps=1e-6):
-    """One-token decode: x [B, 1, d]; returns (y [B, 1, d], cache')."""
+    """One-token decode: x [B, 1, d]; returns (y [B, 1, d], cache').
+
+    Its device ops carry the named scopes ``decode.attention``
+    (projections, KV repeat, scores, output) and ``decode.cache_write``
+    (the two cache updates)."""
     B = x.shape[0]
     t = cache["len"]
-    positions = jnp.full((B, 1), t, jnp.int32)
-    if cfg.mrope_sections is not None:
-        positions = jnp.broadcast_to(positions[None], (3, B, 1))
-    q, k, v = _project_qkv(p, cfg, x, positions=positions, eps=eps)
-    ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, t, axis=1)
-    cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, t, axis=1)
-    S = ck.shape[1]
-    kpos = jnp.arange(S)[None, :]
-    win = window if window is not None else cfg.window
-    mask = (kpos <= t)
-    if win is not None:
-        mask &= kpos > t - win
-    mask = jnp.broadcast_to(mask[:, None, :], (B, 1, S))
-    out = core_attention(q, ck, cv, mask, cap=cfg.softcap)
-    y = out.reshape(B, 1, -1) @ p["wo"]
+    with jax.named_scope("decode.attention"):
+        positions = jnp.full((B, 1), t, jnp.int32)
+        if cfg.mrope_sections is not None:
+            positions = jnp.broadcast_to(positions[None], (3, B, 1))
+        q, k, v = _project_qkv(p, cfg, x, positions=positions, eps=eps)
+    with jax.named_scope("decode.cache_write"):
+        ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, t, axis=1)
+        cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, t, axis=1)
+    with jax.named_scope("decode.attention"):
+        S = ck.shape[1]
+        kpos = jnp.arange(S)[None, :]
+        win = window if window is not None else cfg.window
+        mask = (kpos <= t)
+        if win is not None:
+            mask &= kpos > t - win
+        mask = jnp.broadcast_to(mask[:, None, :], (B, 1, S))
+        out = core_attention(q, ck, cv, mask, cap=cfg.softcap)
+        y = out.reshape(B, 1, -1) @ p["wo"]
     return y, {"k": ck, "v": cv, "len": t + 1}

@@ -76,19 +76,19 @@ def make_decode_step(cfg, mesh, opts: ServeOptions) -> Callable:
     encoder output for enc-dec archs (whisper)."""
 
     if cfg.encoder is not None:
-        def decode(params, cache, tokens, cross_src):
+        def decode_step(params, cache, tokens, cross_src):
             logits, cache = M.decode_step(params, cfg, cache, tokens,
                                           cross_src=cross_src)
             nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             return nxt[:, None], cache
-        return decode
+        return decode_step
 
-    def decode(params, cache, tokens):
+    def decode_step(params, cache, tokens):
         logits, cache = M.decode_step(params, cfg, cache, tokens)
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return nxt[:, None], cache
 
-    return decode
+    return decode_step
 
 
 def _shardings(mesh, spec_tree):

@@ -77,10 +77,11 @@ def _loss_fn(cfg, opts: TrainOptions, moe_dispatch, reduction="mean"):
             kw["encoder_frames"] = batch["encoder_frames"]
         if cfg.vision_prefix:
             kw["vision_embeds"] = batch["vision_embeds"]
-        return M.lm_loss(params, cfg, batch["tokens"], batch["labels"],
-                         use_kernel=opts.use_kernel, remat=opts.remat,
-                         moe_dispatch=moe_dispatch, reduction=reduction,
-                         **kw)
+        with jax.named_scope("train.loss"):
+            return M.lm_loss(params, cfg, batch["tokens"], batch["labels"],
+                             use_kernel=opts.use_kernel, remat=opts.remat,
+                             moe_dispatch=moe_dispatch, reduction=reduction,
+                             **kw)
     return loss
 
 
@@ -108,7 +109,10 @@ def state_specs(state, cfg, mesh, opts: TrainOptions):
 
 
 def make_train_step(cfg, mesh, opts: TrainOptions) -> Callable:
-    """Returns jitted ``step(state, batch) -> (state, metrics)``."""
+    """Returns ``train_step(state, batch) -> (state, metrics)``, to be
+    jitted.  Its device ops carry the named scopes ``train.loss``
+    (forward ``jvp(train.loss)``, backward ``transpose(jvp(train.loss))``),
+    ``train.count_psum``, ``train.grad_sync`` and ``train.optimizer``."""
     moe_dispatch = None
     if opts.moe_mode == "mpix_ep" and cfg.moe is not None:
         moe_dispatch = make_moe_dispatch(
@@ -124,6 +128,7 @@ def make_train_step(cfg, mesh, opts: TrainOptions) -> Callable:
             p, c, x, cfg.mlp_act)
     loss = _loss_fn(cfg, opts, moe_dispatch)
 
+    @jax.named_scope("train.optimizer")
     def opt_apply(state, grads, gnorm=None):
         lr = cosine_schedule(state["step"], peak_lr=opts.peak_lr,
                              warmup_steps=opts.warmup_steps,
@@ -137,13 +142,13 @@ def make_train_step(cfg, mesh, opts: TrainOptions) -> Callable:
     d_axes = sharding.data_axes(mesh)
 
     if opts.dp_mode == "fsdp":
-        def step(state, batch):
+        def train_step(state, batch):
             lval, grads = jax.value_and_grad(loss)(state["params"], batch)
             params, opt, gnorm, lr = opt_apply(state, grads)
             new = dict(state, params=params, opt=opt,
                        step=state["step"] + 1)
             return new, {"loss": lval, "grad_norm": gnorm, "lr": lr}
-        return step
+        return train_step
 
     # ---- explicit mode: replicated params, manual DP sync --------------
     # Per-shard losses are SUMS over live tokens; shards exchange
@@ -158,34 +163,37 @@ def make_train_step(cfg, mesh, opts: TrainOptions) -> Callable:
     overlap = (opts.overlap_grad_chunks > 0
                and not (opts.compress_dcn and "pod" in mesh.axis_names))
 
-    def step(state, batch):
+    def train_step(state, batch):
         def body(params, residual, batch):
             def local(p):
                 s, c = sum_loss(p, batch)
                 return s, c
             (lsum, cnt), grads = jax.value_and_grad(
                 local, has_aux=True)(params)
-            cnt_g = jax.lax.psum(cnt, d_axes)
+            with jax.named_scope("train.count_psum"):
+                cnt_g = jax.lax.psum(cnt, d_axes)
             denom = jnp.maximum(cnt_g, 1).astype(jnp.float32)
             gnorm = None
-            if opts.compress_dcn and "pod" in mesh.axis_names:
-                grads, residual = sync.dp_allreduce_compressed(
-                    grads, residual, intra_algorithm=opts.dp_algorithm,
-                    denom=denom, resilience=opts.resilience)
-            elif overlap:
-                grads, gnorm = sync.dp_allreduce_overlap(
-                    grads, d_axes, algorithm=opts.dp_algorithm,
-                    chunks=opts.overlap_grad_chunks, denom=denom,
-                    max_norm=opts.max_grad_norm,
-                    transport=opts.dp_transport,
-                    resilience=opts.resilience)
-            else:
-                grads = sync.dp_allreduce(
-                    grads, d_axes, algorithm=opts.dp_algorithm,
-                    buckets=opts.grad_buckets, denom=denom,
-                    transport=opts.dp_transport,
-                    resilience=opts.resilience)
-            lval = jax.lax.psum(lsum, d_axes) / denom
+            with jax.named_scope("train.grad_sync"):
+                if opts.compress_dcn and "pod" in mesh.axis_names:
+                    grads, residual = sync.dp_allreduce_compressed(
+                        grads, residual, intra_algorithm=opts.dp_algorithm,
+                        denom=denom, resilience=opts.resilience)
+                elif overlap:
+                    grads, gnorm = sync.dp_allreduce_overlap(
+                        grads, d_axes, algorithm=opts.dp_algorithm,
+                        chunks=opts.overlap_grad_chunks, denom=denom,
+                        max_norm=opts.max_grad_norm,
+                        transport=opts.dp_transport,
+                        resilience=opts.resilience)
+                else:
+                    grads = sync.dp_allreduce(
+                        grads, d_axes, algorithm=opts.dp_algorithm,
+                        buckets=opts.grad_buckets, denom=denom,
+                        transport=opts.dp_transport,
+                        resilience=opts.resilience)
+            with jax.named_scope("train.count_psum"):
+                lval = jax.lax.psum(lsum, d_axes) / denom
             return lval, grads, residual, gnorm
 
         residual = state.get("ef_residual")
@@ -209,7 +217,7 @@ def make_train_step(cfg, mesh, opts: TrainOptions) -> Callable:
             new["ef_residual"] = residual
         return new, {"loss": lval, "grad_norm": gnorm, "lr": lr}
 
-    return step
+    return train_step
 
 
 def jit_train_step(cfg, mesh, opts: TrainOptions, state, batch_spec_tree):
