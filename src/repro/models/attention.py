@@ -160,12 +160,35 @@ def init_cache(cfg: AttnConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
             "len": jnp.zeros((), jnp.int32)}
 
 
+def decode_core(q, ck, cv, mask, *, cap=None):
+    """One query token against the whole cache, grouped by KV head.
+
+    q [B, 1, H, D]; ck, cv [B, T, K, D] as stored; mask [B, 1, T].
+    Query head ``h`` is ``(k, g) = (h // G, h % G)`` with ``G = H // K``,
+    the head ``jnp.repeat(k, G, axis=2)`` pairs it with in
+    ``core_attention``, so the cache is read once in its own layout and
+    dtype: no repeated heads, no f32 copy.  Scores are summed in f32
+    (a product of two bf16 values is exact in f32) and the softmax is f32,
+    as in ``core_attention``.  Returns [B, 1, H * D]."""
+    B, _, H, D = q.shape
+    K = ck.shape[2]
+    qg = q.reshape(B, K, H // K, D)
+    logits = jnp.einsum("bkgd,btkd->bkgt", qg, ck,
+                        preferred_element_type=jnp.float32) * D ** -0.5
+    if cap is not None:
+        logits = softcap(logits, cap)
+    logits = jnp.where(mask[:, :, None, :], logits, -1e30)
+    w = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bkgt,btkd->bkgd", w.astype(cv.dtype), cv)
+    return out.reshape(B, 1, H * D)
+
+
 def decode_step(p, cfg: AttnConfig, x, cache, *, window=None, eps=1e-6):
     """One-token decode: x [B, 1, d]; returns (y [B, 1, d], cache').
 
     Its device ops carry the named scopes ``decode.attention``
-    (projections, KV repeat, scores, output) and ``decode.cache_write``
-    (the two cache updates)."""
+    (projections, the grouped scores and output of ``decode_core``) and
+    ``decode.cache_write`` (the two cache updates)."""
     B = x.shape[0]
     t = cache["len"]
     with jax.named_scope("decode.attention"):
@@ -184,6 +207,6 @@ def decode_step(p, cfg: AttnConfig, x, cache, *, window=None, eps=1e-6):
         if win is not None:
             mask &= kpos > t - win
         mask = jnp.broadcast_to(mask[:, None, :], (B, 1, S))
-        out = core_attention(q, ck, cv, mask, cap=cfg.softcap)
-        y = out.reshape(B, 1, -1) @ p["wo"]
+        out = decode_core(q, ck, cv, mask, cap=cfg.softcap)
+        y = out @ p["wo"]
     return y, {"k": ck, "v": cv, "len": t + 1}
