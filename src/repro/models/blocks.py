@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 from repro.models import attention, mamba, mla, mlp, moe, rwkv
@@ -124,7 +125,8 @@ def init_cache(spec: BlockSpec, cfg: ModelConfig, batch: int, max_len: int):
 
 def decode(p, spec: BlockSpec, cfg: ModelConfig, x, cache, *,
            cross_src=None):
-    """One-token decode; x [B, 1, d]."""
+    """One-token decode; x [B, 1, d].  An MoE feed-forward runs under
+    the named scope ``decode.moe`` (router, held and shared experts)."""
     h = _norm(cfg, x, p["norm_mixer"])
     if spec.mixer == "attn":
         h, cache["attn"] = attention.decode_step(
@@ -159,10 +161,10 @@ def decode(p, spec: BlockSpec, cfg: ModelConfig, x, cache, *,
     if spec.ff == "mlp":
         h = mlp.forward(p["mlp"], h, cfg.mlp_act)
     elif spec.ff == "moe":
-        # capacity dispatch: dense-dispatch FLOPs scale with E, absurd
-        # for one-token decode over 256 experts
-        h = moe.forward_dropless(p["moe"], cfg.moe, h, cfg.mlp_act,
-                                 capacity_factor=2.0)
+        # exact over the held experts: each sees at most B tokens, so
+        # every routed slot is computed, none dropped
+        with jax.named_scope("decode.moe"):
+            h = moe.forward(p["moe"], cfg.moe, h, cfg.mlp_act)
     elif spec.ff == "cmix":
         h, cache["cmix"] = rwkv.decode_channel_mix(p["cmix"], h,
                                                    cache["cmix"])

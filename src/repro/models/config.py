@@ -30,8 +30,10 @@ class AttnConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek multi-head latent attention."""
-    q_lora_rank: int
+    """DeepSeek multi-head latent attention.  ``q_lora_rank`` None: the
+    query is projected directly (``w_q``), with no compression and no
+    ``q_norm`` (Moonlight, DeepSeek-V2-Lite)."""
+    q_lora_rank: int | None
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
@@ -53,6 +55,17 @@ class MoEConfig:
     router: Literal["softmax", "sigmoid"] = "softmax"
     route_scale: float = 1.0
     norm_topk: bool = True              # renormalize top-k weights
+    # expert parallelism: the layer holds experts [expert_offset,
+    # expert_offset + experts_held) of the n_experts the router scores
+    # (None: all of them); slots routed elsewhere add nothing here
+    experts_held: int | None = None
+    expert_offset: int = 0
+
+    @property
+    def n_held(self) -> int:
+        """Rows of the layer's stacked expert weights."""
+        return self.n_experts if self.experts_held is None \
+            else self.experts_held
 
 
 @dataclasses.dataclass(frozen=True)

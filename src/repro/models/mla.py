@@ -1,9 +1,15 @@
 """Multi-head latent attention (DeepSeek-V2/V3).
 
-Queries and KV are projected through low-rank bottlenecks; the rope part
-of the key is shared across heads (computed from the input, not the
-latent).  Cache stores only the compressed latent + rope key: decode
-memory per token is kv_lora_rank + qk_rope_head_dim — the MLA win.
+Queries and KV are projected through low-rank bottlenecks (the query
+directly where ``q_lora_rank`` is None); the rope part of the key is
+shared across heads (computed from the input, not the latent).  Cache
+stores only the compressed latent + rope key: decode memory per token is
+kv_lora_rank + qk_rope_head_dim — the MLA win.
+
+Training and prefill up-project the latent to per-head keys and values
+(``_attend``, the published training form).  Decode attends in latent
+space (``decode_step``): ``W_UK`` is absorbed into the query and
+``W_UV`` into the output (DeepSeek-V2, section 2.1.2).
 """
 from __future__ import annotations
 
@@ -17,10 +23,14 @@ from repro.models.config import MLAConfig
 def init(key, cfg: MLAConfig, d_model: int) -> dict:
     ks = split_keys(key, ["dq", "uq", "dkv", "uk", "uv", "kr", "o"])
     H = cfg.n_heads
-    return {
-        "w_dq": dense_init(ks["dq"], (d_model, cfg.q_lora_rank)),
-        "q_norm": jnp.ones((cfg.q_lora_rank,), jnp.bfloat16),
-        "w_uq": dense_init(ks["uq"], (cfg.q_lora_rank, H * cfg.qk_head_dim)),
+    if cfg.q_lora_rank is None:
+        q = {"w_q": dense_init(ks["uq"], (d_model, H * cfg.qk_head_dim))}
+    else:
+        q = {"w_dq": dense_init(ks["dq"], (d_model, cfg.q_lora_rank)),
+             "q_norm": jnp.ones((cfg.q_lora_rank,), jnp.bfloat16),
+             "w_uq": dense_init(ks["uq"],
+                                (cfg.q_lora_rank, H * cfg.qk_head_dim))}
+    return q | {
         "w_dkv": dense_init(ks["dkv"], (d_model, cfg.kv_lora_rank)),
         "kv_norm": jnp.ones((cfg.kv_lora_rank,), jnp.bfloat16),
         "w_uk": dense_init(ks["uk"],
@@ -34,7 +44,10 @@ def init(key, cfg: MLAConfig, d_model: int) -> dict:
 def _latents(p, cfg: MLAConfig, x, positions, eps):
     B, S, _ = x.shape
     H = cfg.n_heads
-    q = rmsnorm(x @ p["w_dq"], p["q_norm"], eps) @ p["w_uq"]
+    if cfg.q_lora_rank is None:
+        q = x @ p["w_q"]
+    else:
+        q = rmsnorm(x @ p["w_dq"], p["q_norm"], eps) @ p["w_uq"]
     q = q.reshape(B, S, H, cfg.qk_head_dim)
     q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
@@ -142,13 +155,46 @@ def init_cache(cfg: MLAConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
 
 
 def decode_step(p, cfg: MLAConfig, x, cache, *, eps=1e-6, **_):
+    """One-token decode in latent space: x [B, 1, d]; returns
+    (y [B, 1, d], cache').
+
+    The query's no-rope part is carried into the latent by ``W_UK``
+    (``q_lat`` [B, H, kv_lora_rank]); the scores are ``(q_lat . ckv +
+    q_rope . k_rope) * qk_head_dim ** -0.5``, read straight from the
+    cache as stored with f32 sums, then masked and an f32 softmax, as in
+    ``_attend``; the output is ``(sum_t w_t ckv_t) W_UV``, then ``wo``.
+    No per-position key or value of the cache is formed.
+
+    Its device ops carry the named scopes ``decode.attention``
+    (projections, absorbed query, scores, latent output) and
+    ``decode.cache_write`` (the two latent writes)."""
     B = x.shape[0]
     t = cache["len"]
-    positions = jnp.full((B, 1), t, jnp.int32)
-    q_nope, q_rope, ckv, k_rope = _latents(p, cfg, x, positions, eps)
-    c2 = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], ckv, t, axis=1)
-    r2 = jax.lax.dynamic_update_slice_in_dim(cache["kr"], k_rope, t, axis=1)
-    S = c2.shape[1]
-    mask = jnp.broadcast_to((jnp.arange(S) <= t)[None, None, :], (B, 1, S))
-    y = _attend(p, cfg, q_nope, q_rope, c2, r2, mask)
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    with jax.named_scope("decode.attention"):
+        positions = jnp.full((B, 1), t, jnp.int32)
+        q_nope, q_rope, ckv, k_rope = _latents(p, cfg, x, positions, eps)
+    with jax.named_scope("decode.cache_write"):
+        c2 = jax.lax.dynamic_update_slice_in_dim(
+            cache["ckv"], ckv.astype(cache["ckv"].dtype), t, axis=1)
+        r2 = jax.lax.dynamic_update_slice_in_dim(
+            cache["kr"], k_rope.astype(cache["kr"].dtype), t, axis=1)
+    with jax.named_scope("decode.attention"):
+        w_uk = p["w_uk"].reshape(r, H, cfg.qk_nope_head_dim)
+        q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)
+        f32 = jnp.float32
+        logits = (jnp.einsum("bhr,btr->bht", q_lat.astype(c2.dtype), c2,
+                             preferred_element_type=f32)
+                  + jnp.einsum("bhd,btod->bht",
+                               q_rope[:, 0].astype(r2.dtype), r2,
+                               preferred_element_type=f32)) \
+            * cfg.qk_head_dim ** -0.5
+        S = c2.shape[1]
+        logits = jnp.where((jnp.arange(S) <= t)[None, None, :], logits,
+                           -1e30)
+        w = jax.nn.softmax(logits, axis=-1)
+        o_lat = jnp.einsum("bht,btr->bhr", w.astype(c2.dtype), c2)
+        w_uv = p["w_uv"].reshape(r, H, cfg.v_head_dim)
+        out = jnp.einsum("bhr,rhd->bhd", o_lat.astype(x.dtype), w_uv)
+        y = out.reshape(B, 1, H * cfg.v_head_dim) @ p["wo"]
     return y, {"ckv": c2, "kr": r2, "len": t + 1}

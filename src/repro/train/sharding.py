@@ -84,12 +84,13 @@ def param_specs(params, cfg, mesh):
         if nd - lead < 2:
             # stacked vector (periods norm scales etc.)
             return P()
-        # expert-stacked weights: [.., E, a, b] with E == n_experts.
+        # expert-stacked weights: [.., E, a, b] with E the experts the
+        # layer holds (n_experts unless it holds a share).
         # EP storage: experts over ("pod","model") when they divide (the
         # dispatch alltoall then crosses the DCN and the hierarchical
         # algorithm's locality aggregation applies), else "model".
         if cfg.moe is not None and nd - lead == 3 \
-                and shape[lead] == cfg.moe.n_experts:
+                and shape[lead] == cfg.moe.n_held:
             spec = [None] * nd
             ep = ("pod", "model") if "pod" in mesh.axis_names else ("model",)
             if not _divisible(shape[lead], _axis_size(mesh, ep)):

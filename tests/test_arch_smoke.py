@@ -106,9 +106,8 @@ def test_full_param_counts():
         "qwen3-14b": (13e9, 16e9),
         "smollm-360m": (0.3e9, 0.45e9),
         "deepseek-v3-671b": (630e9, 700e9),
-        # assignment pins 48L (actual Moonlight-16B has 27L); with the
-        # assigned depth the same family lands at ~28B (see DESIGN.md §5)
-        "moonshot-v1-16b-a3b": (26e9, 30e9),
+        # Moonlight-16B-A3B as published: 15.96B
+        "moonshot-v1-16b-a3b": (15.5e9, 16.5e9),
         "rwkv6-3b": (2.7e9, 3.6e9),
         "whisper-small": (0.2e9, 0.3e9),
         "qwen2-vl-7b": (6.5e9, 8.5e9),

@@ -86,3 +86,22 @@ def test_decode_step_scopes():
     assert has(names, "decode.attention")
     writes = [n for n in names if "decode.cache_write" in n.split("/")]
     assert sum(n.endswith("/dynamic_update_slice") for n in writes) == 2
+
+
+def test_moonlight_decode_step_scopes():
+    """Moonlight's decode step: latent attention, its two latent writes
+    (in the dense prefix block and in the scanned MoE block) and the MoE
+    layer (router, held and shared experts) each under its scope."""
+    cfg = configs.get_smoke("moonshot-v1-16b-a3b")
+    params = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
+    cache = jax.eval_shape(lambda: init_serve_cache(cfg, 2, 16))
+    step = make_decode_step(cfg, None, ServeOptions())
+    tokens = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    names = op_names(jax.jit(step).lower(params, cache, tokens))
+    for scope in ("decode.attention", "decode.cache_write", "decode.moe"):
+        assert has(names, scope), scope
+    writes = [n for n in names if "decode.cache_write" in n.split("/")]
+    assert sum(n.endswith("/dynamic_update_slice") for n in writes) == 4
+    moe = [n for n in names if "decode.moe" in n.split("/")]
+    assert any("top_k" in n for n in moe)
+    assert not any("decode.attention" in n.split("/") for n in moe)
