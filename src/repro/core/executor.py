@@ -54,6 +54,20 @@ The compile pass (all steps skipped with ``optimize=False`` or
      ``jnp.where`` gather/scatter masks (plus scratch-safe indices) are
      materialized once (numpy for the simulator, device constants for
      shard_map) instead of per trace.
+  7. **Slice lowering** (shard_map) — a round in which every rank sends
+     and receives, gathering one run of consecutive live slots and
+     scattering into one such run (the ring, recursive-halving/doubling
+     and pairwise rounds), keeps only a start slot per rank for each
+     side and lowers as a ``dynamic_slice`` of the buffer, the
+     ``ppermute``, and a ``dynamic_update_slice`` (of ``x[t] + recvd``
+     when it reduces) that XLA does in place.  Every other round
+     (Bruck rotations, masked slots, idle ranks, scattered slot sets)
+     keeps the table lowering: a gather through its index table, the
+     ``jnp.where`` masks and a scatter, with ``-1`` slots routed through
+     one scratch row appended to the buffer.  The scratch row, which
+     costs a copy of the whole buffer, is appended only when some round
+     needs it.  Both lowerings compute the same sums from the same
+     operands, so the results are bitwise equal.
 
 Both transports route through here (``transport.SimTransport`` /
 ``ShardMapTransport.run`` are thin lookups).  The executor cache is
@@ -680,11 +694,23 @@ def _fold_pre(schedule: CommSchedule):
 # ---------------------------------------------------------------------------
 
 
+def _run_starts(table: np.ndarray) -> np.ndarray | None:
+    """Each row's first slot when every row of ``table`` is one
+    ascending run of consecutive live slot ids, else None."""
+    start = table[:, :1]
+    if (start < 0).any() or not np.array_equal(
+            table, start + np.arange(table.shape[1])):
+        return None
+    return table[:, 0].astype(np.int32)
+
+
 class _ExecRound:
     """One compiled round: full per-rank tables (shard_map) plus dense
-    per-edge tables (vectorized simulator), baked once."""
+    per-edge tables (vectorized simulator), baked once.  ``starts`` is
+    (gather start, scatter start) per rank when the round takes the
+    slice lowering (compile step 7), else None."""
 
-    def __init__(self, rnd: CommRound, num_slots: int):
+    def __init__(self, rnd: CommRound, num_slots: int, slices: bool):
         self.perm = rnd.perm
         self.reduce = rnd.reduce
         self.k = rnd.k
@@ -706,6 +732,13 @@ class _ExecRound:
         self.dup_targets = rnd.reduce and any(
             len(np.unique(row[m])) != int(m.sum())
             for row, m in zip(t, self.t_mask))
+        self.starts = None
+        # a full matching: every rank sends and receives in this round
+        if slices and len(rnd.perm) == len(self.gather_idx):
+            g0 = _run_starts(self.gather_idx)
+            t0 = _run_starts(self.scatter_idx)
+            if g0 is not None and t0 is not None:
+                self.starts = (g0, t0)
         self._tables = None
 
     def tables(self):
@@ -748,7 +781,9 @@ class CompiledExec:
     bit-exactness contract between the transports is preserved by
     construction.  Counters: ``trace_count`` (shard_map lowerings —
     one per (shape, dtype, mesh) when the jit layer caches properly),
-    ``sim_runs`` (simulator executions).
+    ``sim_runs`` (simulator executions), ``slice_rounds`` /
+    ``table_rounds`` (compiled rounds the shard_map backend lowers as
+    dynamic slices / through index tables).
     """
 
     def __init__(self, schedule: CommSchedule, optimize: bool,
@@ -808,8 +843,10 @@ class CompiledExec:
             local_post=self.local_post, out_slots=schedule.out_slots,
             out_offsets=schedule.out_offsets)
         self.rounds_after = len(compiled_rounds)
-        self._rounds = tuple(_ExecRound(r, self.num_slots)
+        self._rounds = tuple(_ExecRound(r, self.num_slots, optimize)
                              for r in compiled_rounds)
+        self.slice_rounds = sum(r.starts is not None for r in self._rounds)
+        self.table_rounds = len(self._rounds) - self.slice_rounds
         # pass 3: makespan planning (pricing only; never touches the
         # executed rounds, so every modeled_time/bit-exactness contract
         # above is untouched by construction)
@@ -996,15 +1033,24 @@ class CompiledExec:
 
     # -- shard_map backend (called inside an ambient shard_map trace) -----
     def run_shardmap(self, buf, rank, axis_arg):
+        """Trace the compiled rounds on this rank's ``buf`` [num_slots,
+        *slot].  A round with ``starts`` (compile step 7) moves one run
+        of consecutive slots: it is lowered as dynamic slices that XLA
+        updates in place, with no index table, mask or scatter.  Any
+        other round is lowered through its tables, and then one scratch
+        row is appended to the buffer for its ``-1`` slots; a schedule
+        made only of slice rounds never copies the buffer to add it."""
         self.trace_count += 1
         nb = self.num_slots
         if self._pre is not None:
             buf = buf[jnp.asarray(self._pre, jnp.int32)[rank]]
-        scratch = jnp.zeros((1,) + buf.shape[1:], buf.dtype)
-        x = jnp.concatenate([buf, scratch], axis=0)
+        x = buf
+        if self.table_rounds:
+            scratch = jnp.zeros((1,) + buf.shape[1:], buf.dtype)
+            x = jnp.concatenate([buf, scratch], axis=0)
         for rnd in self._rounds:
             x = self._shardmap_round(rnd, x, rank, axis_arg, nb)
-        out = x[:nb]
+        out = x[:nb] if self.table_rounds else x
         if self._post is not None:
             out = out[jnp.asarray(self._post, jnp.int32)[rank]]
         return out
@@ -1012,6 +1058,14 @@ class CompiledExec:
     def _shardmap_round(self, rnd: _ExecRound, x, rank, axis_arg, nb):
         import jax
 
+        if rnd.starts is not None:
+            g0, t0 = (jnp.asarray(s)[rank] for s in rnd.starts)
+            payload = jax.lax.dynamic_slice_in_dim(x, g0, rnd.k)
+            recvd = jax.lax.ppermute(payload, axis_arg, list(rnd.perm))
+            if rnd.reduce:
+                # the same operands as the table lowering's scatter-add
+                recvd = jax.lax.dynamic_slice_in_dim(x, t0, rnd.k) + recvd
+            return jax.lax.dynamic_update_slice_in_dim(x, recvd, t0, 0)
         kdims = (rnd.k,) + (1,) * (x.ndim - 1)
         # safe indices and where-masks are baked numpy tables
         # (``tables``), embedded here as constants of this trace
@@ -1056,6 +1110,8 @@ class CompiledExec:
                          if it[0] == "r")),
             "pipeline_tail_parts": self.pipeline_tail_parts,
             "pre_folded": self.pre_folded,
+            "slice_rounds": self.slice_rounds,
+            "table_rounds": self.table_rounds,
             "trace_count": self.trace_count,
             "sim_runs": self.sim_runs,
         }

@@ -25,7 +25,10 @@ Buffers are slot-indexed: the working array has shape
 ``[num_slots + 1, *slot_shape]`` on every rank — the final slot is a
 scratch row that absorbs sends/receives masked out with ``-1`` in the
 schedule tables, so execution is fully static (no data-dependent control
-flow, as required for TPU lowering).
+flow, as required for TPU lowering).  The shard_map lowering appends that
+row only when a round needs it: rounds that move one run of consecutive
+slots on every rank are lowered as in-place dynamic slices
+(``core.executor``, compile step 7).
 
 Since the persistent-executor compilation (core.executor) both ``run``
 methods are thin lookups: the schedule is lowered once to a cached
@@ -215,8 +218,8 @@ class ShardMapTransport(Transport):
     ``run`` must be called from *inside* a shard_map whose manual axes
     include ``axis_names`` (row-major order defines the flat rank, matching
     the CommSchedule's rank numbering).  ``buf`` here is the *local*
-    buffer, shape [num_slots, *slot], and one scratch slot is appended
-    internally.
+    buffer, shape [num_slots, *slot]; one scratch slot is appended
+    internally when some round is lowered through its index tables.
     """
 
     def __init__(self, nranks: int, axis_names: Sequence[str] | str,

@@ -460,6 +460,73 @@ def test_sim_run_counter_and_stats():
 
 
 # ---------------------------------------------------------------------------
+# shard_map round lowerings: in-place slices vs index tables
+# ---------------------------------------------------------------------------
+
+
+SLICE_COLLECTIVES = ("allreduce", "reduce_scatter", "allgather", "alltoall")
+# the registered schedules whose compiled rounds do not all move one run
+# of consecutive slots on every rank (Bruck rotations), by collective
+TABLE_LOWERED = {"allgather.bruck", "allgather.hierarchical",
+                 "alltoall.bruck"}
+# exact slice-round counts pinned for the grad-sync schedules (4 ranks)
+SLICE_ROUNDS_4 = {"allreduce.recursive_halving_doubling": 4,
+                  "allreduce.ring_rs_ag": 6}
+
+
+@pytest.fixture(scope="module")
+def shardmap_lowerings():
+    """One subprocess per rank count (forced host devices): every
+    schedule's shard_map result against the reference, its round
+    counters, and the compiled HLO's op counts on 4 ranks."""
+    import json
+
+    from test_shardmap import run_script
+    return {n: json.loads(run_script("check_slice_rounds.py",
+                                     str(n)).strip().splitlines()[-1])
+            for n in (4, 8)}
+
+
+@pytest.mark.parametrize("nranks", [4, 8])
+@pytest.mark.parametrize("label", [f"{c}.{a}" for c in SLICE_COLLECTIVES
+                                   for a in REGISTRY[c]])
+def test_shardmap_slice_rounds_bit_exact(shardmap_lowerings, nranks,
+                                         label):
+    """Each round is lowered as in-place dynamic slices when every rank
+    moves one run of consecutive slots, through index tables otherwise;
+    either way the shard_map result is bitwise the reference's."""
+    got = shardmap_lowerings[nranks]["schedules"][label]
+    assert got["bitwise"], (nranks, label)
+    assert got["slice_rounds"] + got["table_rounds"] == got["rounds"]
+    if label in TABLE_LOWERED:
+        assert got["table_rounds"] > 0, (nranks, label, got)
+    else:
+        assert got["table_rounds"] == 0, (nranks, label, got)
+    if nranks == 4 and label in SLICE_ROUNDS_4:
+        assert got["slice_rounds"] == SLICE_ROUNDS_4[label], got
+
+
+def test_shardmap_rhd_allreduce_hlo_has_no_table_ops(shardmap_lowerings):
+    """The 4-rank recursive_halving_doubling mpix_allreduce compiles with
+    no gather, no scatter and no concatenate (no scratch-padded copy of
+    the buffer): all its rounds take the slice lowering."""
+    assert shardmap_lowerings[4]["hlo"] == {
+        "gather": 0, "scatter": 0, "concatenate": 0}
+
+
+def test_slice_rounds_counted_in_stats():
+    """``stats()`` counts the rounds of each lowering; the unoptimized
+    executor keeps the historical table lowering for every round."""
+    topo = flat_topology(8)
+    ag = executor.get_executor(REGISTRY["allgather"]["bruck"](topo))
+    st_ = ag.stats()
+    assert (st_["slice_rounds"], st_["table_rounds"]) == (1, 2)
+    plain = executor.compile_schedule(
+        REGISTRY["allreduce"]["ring_rs_ag"](topo), optimize=False)
+    assert (plain.slice_rounds, plain.table_rounds) == (0, 14)
+
+
+# ---------------------------------------------------------------------------
 # schedule fingerprint
 # ---------------------------------------------------------------------------
 

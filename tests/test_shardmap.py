@@ -12,10 +12,10 @@ SCRIPTS = Path(__file__).parent / "device_scripts"
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
 
-def run_script(name: str, timeout=900):
+def run_script(name: str, *args: str, timeout=900):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run([sys.executable, str(SCRIPTS / name)],
+    proc = subprocess.run([sys.executable, str(SCRIPTS / name), *args],
                           capture_output=True, text=True, timeout=timeout,
                           env=env)
     assert proc.returncode == 0, (
